@@ -2,16 +2,18 @@
 
     PYTHONPATH=src python examples/serve_decode.py [--arch rwkv6-7b-smoke]
 
-Serves a reduced-config model on 8 forced host devices: batch prefill of
+Serves a reduced-config model on a mesh over every device JAX sees (8
+virtual devices under ``JAX_PLATFORMS=cpu``): batch prefill of
 mixed prompts, then greedy decode steps, exercising the serve path the
 decode_32k / long_500k dry-run cells compile at full scale (KV/ring/state
 caches included).
 """
 
 import argparse
-import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+from repro.launch.mesh import split_cpu_host
+
+split_cpu_host()
 
 
 def main():
@@ -27,12 +29,12 @@ def main():
     import numpy as np
 
     from repro.configs.registry import get_smoke_config
-    from repro.launch.mesh import make_small_mesh
+    from repro.launch.mesh import default_mesh
     from repro.models.model import forward, init_cache, init_params
     from repro.train.train_step import TrainConfig, build_serve_step
 
     cfg = get_smoke_config(args.arch)
-    mesh = make_small_mesh()
+    mesh = default_mesh()
     tcfg = TrainConfig()
     rng = np.random.default_rng(0)
 

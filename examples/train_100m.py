@@ -3,15 +3,17 @@
     PYTHONPATH=src python examples/train_100m.py                  # ~100M, 300 steps
     PYTHONPATH=src python examples/train_100m.py --small --steps 40   # CI-sized
 
-Runs on 8 forced host devices arranged as a (2, 2, 2) = (pod, data, model)
-mesh: FSDP+TP inside each pod (GSPMD) and GeoCoCo's filtered top-k exchange
-across the pod (WAN-analogue) boundary, with periodic checkpointing.
+Runs on a (pod, data, model) mesh over every device JAX sees -- (2, 2, 2)
+on the 8 virtual devices ``JAX_PLATFORMS=cpu`` gets: FSDP+TP inside each
+pod (GSPMD) and GeoCoCo's filtered top-k exchange across the pod
+(WAN-analogue) boundary, with periodic checkpointing.
 """
 
 import argparse
-import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+from repro.launch.mesh import split_cpu_host
+
+split_cpu_host()
 
 
 def main():
@@ -24,14 +26,12 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/geococo_train_100m")
     args = ap.parse_args()
 
-    import dataclasses
-
     import jax
 
     from repro.configs.base import Block, ModelConfig
     from repro.data.pipeline import DataConfig
     from repro.dist.collectives import SyncConfig
-    from repro.launch.mesh import make_small_mesh
+    from repro.launch.mesh import default_mesh
     from repro.models.model import param_count
     from repro.optim.adamw import AdamWConfig
     from repro.train.train_step import TrainConfig
@@ -55,7 +55,7 @@ def main():
 
     print(f"model {cfg.name}: {param_count(cfg)/1e6:.1f}M params; "
           f"devices {jax.device_count()}, sync={args.sync}")
-    mesh = make_small_mesh()
+    mesh = default_mesh()
     tcfg = TrainConfig(
         sync=SyncConfig(strategy=args.sync, density=0.10, chunk=2048,
                         min_leaf_size=16_384),

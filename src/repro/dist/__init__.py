@@ -8,13 +8,11 @@ resolve through the shared registry in ``repro.core.strategies``.
 
 Modules:
 
-* :mod:`~repro.dist.compat`      — JAX version shim (installed on import)
 * :mod:`~repro.dist.collectives` — ``SyncConfig`` + pod-boundary collectives
 * :mod:`~repro.dist.context`     — distribution context for model layers
 * :mod:`~repro.dist.sharding`    — per-strategy parameter partitioning
 """
 
-from . import compat  # noqa: F401  (installs the modern-API shims)
 from .collectives import (
     DeviceSyncStrategy,
     SyncConfig,

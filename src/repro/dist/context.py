@@ -15,18 +15,12 @@ import dataclasses
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat
-
 __all__ = ["DistContext", "distribution", "current"]
 
 
 @dataclasses.dataclass(frozen=True)
 class DistContext:
     mesh: Mesh
-
-    @property
-    def pod_size(self) -> int:
-        return self.mesh.shape.get("pod", 1)
 
     @property
     def data_size(self) -> int:
@@ -48,19 +42,12 @@ class DistContext:
             x, NamedSharding(self.mesh, P(None, None, "model", None))
         )
 
-    @property
-    def supports_manual_subregions(self) -> bool:
-        """Whether a manual shard_map subregion (e.g. MoE expert-parallel
-        dispatch) can be used under this runtime.  Requires either a
-        pod-free mesh (full-manual covers all axes) or a runtime with
-        working partial-auto shard_map."""
-        return compat.has_partial_auto() or self.pod_size <= 1
-
     def shard_map(self, fn, *, in_specs, out_specs, axis_names):
-        """Manual subregion over ``axis_names`` of the context mesh."""
-        return compat.shard_map(
-            fn, self.mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(axis_names),
+        """Manual subregion over ``axis_names`` of the context mesh; the
+        other axes stay with GSPMD."""
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            axis_names=set(axis_names), check_vma=False,
         )
 
 

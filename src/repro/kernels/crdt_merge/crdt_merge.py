@@ -7,7 +7,9 @@ the VPU over (bm, bn) VMEM tiles.  Versions ride along as a (bm, 1) column
 so one row-predicate broadcasts across the payload tile.
 
 Grid: (M / bm, N / bn); versions are written only by the first column
-program (j == 0) to avoid redundant stores.
+program (j == 0) to avoid redundant stores.  Blocks are multiples of the
+(sublane, 128) tile of the payload dtype; a payload that is not a whole
+number of blocks is zero-padded in rows and lanes and sliced back.
 """
 
 from __future__ import annotations
@@ -17,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .. import interpret_mode, round_up, sublane_tile
 
 DEFAULT_BLOCK = (256, 256)
 
@@ -39,16 +43,20 @@ def crdt_merge_pallas(
     ver_b: jnp.ndarray,
     *,
     block: tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    interpret = interpret_mode(interpret)
     m, n = val_a.shape
-    bm = min(block[0], m)
-    bn = min(block[1], n)
-    if m % bm or n % bn:
-        raise ValueError(f"shape {(m, n)} not divisible by block {(bm, bn)}")
-    grid = (m // bm, n // bn)
-    ra = ver_a.reshape(m, 1)
-    rb = ver_b.reshape(m, 1)
+    bm = min(block[0], round_up(m, sublane_tile(val_a.dtype)))
+    bn = min(block[1], round_up(n, 128))
+    mp, np_ = round_up(m, bm), round_up(n, bn)
+    if (mp, np_) != (m, n):
+        pad = ((0, mp - m), (0, np_ - n))
+        val_a, val_b = jnp.pad(val_a, pad), jnp.pad(val_b, pad)
+        ver_a, ver_b = jnp.pad(ver_a, pad[0]), jnp.pad(ver_b, pad[0])
+    grid = (mp // bm, np_ // bn)
+    ra = ver_a.reshape(mp, 1)
+    rb = ver_b.reshape(mp, 1)
 
     out_val, out_ver = pl.pallas_call(
         _merge_kernel,
@@ -64,9 +72,9 @@ def crdt_merge_pallas(
             pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, n), val_a.dtype),
-            jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((mp, np_), val_a.dtype),
+            jax.ShapeDtypeStruct((mp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(val_a, ra, val_b, rb)
-    return out_val, out_ver.reshape(m)
+    return out_val[:m, :n], out_ver[:m, 0]

@@ -2,41 +2,28 @@
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
-from .crdt_merge import DEFAULT_BLOCK, crdt_merge_pallas
+from .crdt_merge import crdt_merge_pallas
 from .ref import crdt_merge_ref
 
 __all__ = ["crdt_merge", "crdt_merge_many", "crdt_merge_ref"]
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def crdt_merge(
     val_a, ver_a, val_b, ver_b, *, use_kernel: bool = True,
     interpret: bool | None = None,
 ):
-    """Merge two versioned slot batches: (M, N) payloads + (M,) versions."""
+    """Merge two versioned slot batches: (M, N) payloads + (M,) versions.
+
+    ``interpret=None`` interprets on the CPU backend only (see
+    :func:`repro.kernels.interpret_mode`)."""
     if not use_kernel:
         return crdt_merge_ref(val_a, ver_a, val_b, ver_b)
-    interpret = (not _ON_TPU) if interpret is None else interpret
-    m, n = val_a.shape
-    bm = _div_block(m, DEFAULT_BLOCK[0])
-    bn = _div_block(n, DEFAULT_BLOCK[1])
     return crdt_merge_pallas(
         val_a, ver_a.astype(jnp.int32), val_b, ver_b.astype(jnp.int32),
-        block=(bm, bn), interpret=interpret,
+        interpret=interpret,
     )
-
-
-def _div_block(n: int, target: int) -> int:
-    b = min(target, n)
-    while n % b:
-        b -= 1
-    return b
 
 
 def crdt_merge_many(batches, *, use_kernel: bool = True):

@@ -2,15 +2,12 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .ref import rglru_scan_ref
 from .rglru_scan import rglru_scan_pallas
 
 __all__ = ["rglru_scan", "rglru_scan_ref"]
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def rglru_scan(
@@ -23,7 +20,6 @@ def rglru_scan(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     if not use_kernel:
         return rglru_scan_ref(a, b, h0)
-    interpret = (not _ON_TPU) if interpret is None else interpret
     return rglru_scan_pallas(
         a.astype(jnp.float32), b.astype(jnp.float32), h0.astype(jnp.float32),
         interpret=interpret,

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import interpret_mode
+
 DEFAULT_TIME_CHUNK = 256
 DEFAULT_CHANNEL_BLOCK = 512
 
@@ -29,7 +31,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hfin_ref, state):
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
-        state[...] = h0_ref[...]
+        state[...] = h0_ref[0]
 
     def step(t, carry):
         h = a_ref[0, t, :] * state[0, :] + b_ref[0, t, :]
@@ -41,7 +43,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hfin_ref, state):
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _fin():
-        hfin_ref[...] = state[...]
+        hfin_ref[0] = state[...]
 
 
 @functools.partial(jax.jit, static_argnames=("time_chunk", "channel_block", "interpret"))
@@ -52,10 +54,11 @@ def rglru_scan_pallas(
     *,
     time_chunk: int = DEFAULT_TIME_CHUNK,
     channel_block: int = DEFAULT_CHANNEL_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     from jax.experimental.pallas import tpu as pltpu
 
+    interpret = interpret_mode(interpret)
     bsz, t, d = a.shape
     tc = min(time_chunk, t)
     while t % tc:
@@ -70,23 +73,25 @@ def rglru_scan_pallas(
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    return pl.pallas_call(
+    # (B, 1, D) state blocks keep the (8, 128) tiling legal for any B
+    h, h_fin = pl.pallas_call(
         _rglru_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tc, bd), lambda i, j, k: (i, k, j)),
             pl.BlockSpec((1, tc, bd), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, bd), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, 1, bd), lambda i, j, k: (i, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, tc, bd), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, bd), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, 1, bd), lambda i, j, k: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, t, d), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, d), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, 1, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(a, b, h0)
+    )(a, b, h0.reshape(bsz, 1, d))
+    return h, h_fin.reshape(bsz, d)

@@ -2,15 +2,12 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .ref import wkv6_ref
 from .rwkv6_wkv import wkv6_pallas
 
 __all__ = ["wkv6", "wkv6_ref"]
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def wkv6(
@@ -28,7 +25,6 @@ def wkv6(
     """Model-facing WKV6: returns (y (B,T,H,N), final_state)."""
     if not use_kernel:
         return wkv6_ref(r, k, v, w, u, state)
-    interpret = (not _ON_TPU) if interpret is None else interpret
     b, t, h, n = r.shape
 
     def to_bh(x):

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import interpret_mode
+
 DEFAULT_TIME_CHUNK = 128
 
 
@@ -38,7 +40,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
         kt = k_ref[0, t, :]
         vt = v_ref[0, t, :]
         wt = w_ref[0, t, :]
-        u = u_ref[0, :]
+        u = u_ref[0, 0, :]
         s = state[...]                          # (N, N)
         kv = kt[:, None] * vt[None, :]          # (N, N)
         y = (rt[:, None] * (s + u[:, None] * kv)).sum(axis=0)   # (N,)
@@ -59,14 +61,15 @@ def wkv6_pallas(
     k: jnp.ndarray,
     v: jnp.ndarray,
     w: jnp.ndarray,
-    u: jnp.ndarray,     # (BH, N)
+    u: jnp.ndarray,     # (BH, N); a (BH, 1, N) block keeps the tiling legal
     s0: jnp.ndarray,    # (BH, N, N)
     *,
     time_chunk: int = DEFAULT_TIME_CHUNK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     from jax.experimental.pallas import tpu as pltpu
 
+    interpret = interpret_mode(interpret)
     bh, t, n = r.shape
     tc = min(time_chunk, t)
     while t % tc:
@@ -86,7 +89,7 @@ def wkv6_pallas(
             pl.BlockSpec((1, tc, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, tc, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, tc, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, n), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, n), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, n, n), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
@@ -100,4 +103,4 @@ def wkv6_pallas(
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(bh, 1, n), s0)

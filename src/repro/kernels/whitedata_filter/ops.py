@@ -1,24 +1,20 @@
 """Public jit'd wrapper for the white-data gradient filter.
 
 Handles arbitrary pytrees / shapes by flattening to padded 2-D tiles, calls
-the Pallas kernel (interpret mode on CPU, compiled on TPU), and exposes the
-high-level ``filter_gradient`` used by the geococo sync strategy.
+the Pallas kernel (interpreted on the CPU backend, compiled on a TPU), and
+exposes the pytree-level ``filter_gradient``.
 """
 
 from __future__ import annotations
 
-import functools
-import math
-
 import jax
 import jax.numpy as jnp
 
+from .. import round_up, sublane_tile
 from .ref import whitedata_filter_ref
 from .whitedata_filter import DEFAULT_BLOCK, whitedata_filter_pallas
 
 __all__ = ["whitedata_filter", "filter_gradient", "whitedata_filter_ref"]
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def whitedata_filter(
@@ -29,30 +25,32 @@ def whitedata_filter(
     use_kernel: bool = True,
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Filter one array (any shape).  Returns (send, new_r, kept)."""
+    """Filter one array (any shape).  Returns (send, new_r, kept).
+
+    ``interpret=None`` interprets on the CPU backend only (see
+    :func:`repro.kernels.interpret_mode`)."""
     if not use_kernel:
         return whitedata_filter_ref(g, r, tau)
-    interpret = (not _ON_TPU) if interpret is None else interpret
     shape = g.shape
     size = g.size
     bm, bn = DEFAULT_BLOCK
-    if size % bn:
-        # pad the flat vector up to a tile multiple
-        pad = bn - size % bn
-        gf = jnp.concatenate([g.reshape(-1), jnp.zeros(pad, g.dtype)])
-        rf = jnp.concatenate([r.reshape(-1), jnp.zeros(pad, r.dtype)])
-    else:
-        pad = 0
-        gf, rf = g.reshape(-1), r.reshape(-1)
-    rows = gf.size // bn
-    bm_eff = math.gcd(rows, bm) if rows % bm else bm
+    rows = -(-size // bn)
+    bm = min(bm, round_up(rows, sublane_tile(g.dtype)))
+    rows = round_up(rows, bm)
+    pad = rows * bn - size
+    gf, rf = g.reshape(-1), r.reshape(-1)
+    if pad:
+        gf = jnp.concatenate([gf, jnp.zeros(pad, g.dtype)])
+        rf = jnp.concatenate([rf, jnp.zeros(pad, r.dtype)])
     send, new_r, kept = whitedata_filter_pallas(
         gf.reshape(rows, bn), rf.reshape(rows, bn), tau,
-        block=(bm_eff, bn), interpret=interpret,
+        block=(bm, bn), interpret=interpret,
     )
+    # the zero padding passes |0| >= tau exactly when tau <= 0
+    kept = kept - jnp.where(jnp.asarray(tau, jnp.float32) <= 0, pad, 0)
     send = send.reshape(-1)[:size].reshape(shape)
     new_r = new_r.reshape(-1)[:size].reshape(shape)
-    return send, new_r, kept
+    return send, new_r, kept.astype(jnp.int32)
 
 
 def filter_gradient(grads, residuals, tau, *, use_kernel: bool = True):

@@ -6,8 +6,10 @@ purely elementwise + a block reduction — a VPU kernel (no MXU), bound by
 HBM bandwidth; fusing the four ops quarters the bytes moved.
 
 Grid: 2-D over (M / bm, N / bn) row-major; each program handles one
-(bm, bn) VMEM tile.  ``kept`` is a per-program partial count reduced by the
-wrapper (keeps the kernel free of cross-program communication).
+(bm, bn) VMEM tile.  ``kept`` is a per-program (8, bn) block of per-lane
+partial counts (a legal (8, 128)-tiled layout), summed by the wrapper — the
+kernel stays free of cross-program communication.  ``tau`` is a scalar in
+SMEM.
 """
 
 from __future__ import annotations
@@ -17,6 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .. import interpret_mode
 
 DEFAULT_BLOCK = (256, 256)  # multiples of the (8, 128) float32 VMEM tile
 
@@ -29,7 +34,8 @@ def _filter_kernel(g_ref, r_ref, tau_ref, send_ref, newr_ref, kept_ref):
     keep = jnp.abs(acc) >= tau
     send_ref[...] = jnp.where(keep, acc, 0.0).astype(send_ref.dtype)
     newr_ref[...] = jnp.where(keep, 0.0, acc).astype(newr_ref.dtype)
-    kept_ref[0, 0] = keep.sum(dtype=jnp.int32)
+    bm, bn = keep.shape
+    kept_ref[...] = keep.astype(jnp.int32).reshape(bm // 8, 8, bn).sum(axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -39,14 +45,18 @@ def whitedata_filter_pallas(
     tau: jnp.ndarray,
     *,
     block: tuple[int, int] = DEFAULT_BLOCK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """g, r: (M, N); tau: () scalar.  Returns (send, new_r, kept_count)."""
+    """g, r: (M, N); tau: () scalar.  Returns (send, new_r, kept_count).
+
+    M and N must be multiples of the block, whose rows are a multiple of 8
+    and lanes of 128 (the wrapper in ``ops`` pads)."""
+    interpret = interpret_mode(interpret)
     m, n = g.shape
     bm = min(block[0], m)
     bn = min(block[1], n)
-    if m % bm or n % bn:
-        raise ValueError(f"shape {(m, n)} not divisible by block {(bm, bn)}")
+    if m % bm or n % bn or bm % 8 or bn % 128:
+        raise ValueError(f"shape {(m, n)} not tiled by block {(bm, bn)}")
     grid = (m // bm, n // bn)
     tau_arr = jnp.asarray(tau, jnp.float32).reshape(1)
 
@@ -56,17 +66,17 @@ def whitedata_filter_pallas(
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pl.ANY),     # tau: tiny, replicated
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((8, bn), lambda i, j: (i, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), g.dtype),
             jax.ShapeDtypeStruct((m, n), r.dtype),
-            jax.ShapeDtypeStruct(grid, jnp.int32),
+            jax.ShapeDtypeStruct((grid[0] * 8, n), jnp.int32),
         ],
         interpret=interpret,
     )(g, r, tau_arr)

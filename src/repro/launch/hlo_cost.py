@@ -29,7 +29,7 @@ from typing import Any
 
 import numpy as np
 
-__all__ = ["HLOCost", "analyze_hlo", "classify_groups"]
+__all__ = ["HLOCost", "analyze_hlo", "classify_groups", "collectives_over"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -271,6 +271,22 @@ def classify_groups(attrs: str, mesh_shape: dict[str, int]) -> tuple[frozenset, 
         if len(set(coords_arr[:, i].tolist())) > 1
     )
     return axes, len(group0)
+
+
+_COLLECTIVE_RE = re.compile(
+    r"=.*?\s(" + "|".join(sorted(_COLLECTIVES)) + r")(?:-start)?\("
+)
+
+
+def collectives_over(text: str, mesh_shape: dict[str, int], axis: str) -> list[str]:
+    """Ops of the collectives in ``text`` whose replica groups span ``axis``
+    (async ``-start`` halves counted once, under the op's plain name)."""
+    ops = []
+    for line in text.splitlines():
+        m = _COLLECTIVE_RE.search(line)
+        if m and axis in classify_groups(line, mesh_shape)[0]:
+            ops.append(m.group(1))
+    return ops
 
 
 # ---------------------------------------------------------------------------

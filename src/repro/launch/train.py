@@ -1,11 +1,12 @@
 """Training entry point.
 
-    PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python -m repro.launch.train --arch minitron-8b --smoke \
-        --mesh 2,2,2 --sync geococo --steps 100
+    PYTHONPATH=src python -m repro.launch.train --arch granite-moe-3b-a800m \
+        --sync geococo --steps 100
 
-On real hardware the same entry point runs the full configs; on this CPU
-container use --smoke (reduced config) with a forced device count.
+The mesh defaults to ``default_mesh_shape(jax.device_count())``.  Under
+``JAX_PLATFORMS=cpu`` the host is split into as many virtual devices as
+``--mesh`` asks for (8 without ``--mesh``); use ``--smoke`` (reduced
+config) there.
 """
 
 from __future__ import annotations
@@ -17,8 +18,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--mesh", default="2,2,2",
-                    help="pod,data,model sizes (product = device count)")
+    ap.add_argument("--mesh", default=None,
+                    help="pod,data,model sizes (product = device count); "
+                         "default: derived from the device count")
     ap.add_argument("--sync", default="hier",
                     help="registered device_sync strategy (flat/hier/geococo/"
                          "...); validated against the registry once jax is up")
@@ -38,29 +40,27 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    import os
+    import math
 
-    shape = tuple(int(x) for x in args.mesh.split(","))
-    n_dev = 1
-    for s in shape:
-        n_dev *= s
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={n_dev}"
-    )
+    from .compile_cache import enable_compile_cache
+    from .mesh import default_mesh, make_mesh, split_cpu_host
 
-    import jax
+    enable_compile_cache()
+    shape = tuple(int(x) for x in args.mesh.split(",")) if args.mesh else None
+    split_cpu_host(math.prod(shape) if shape else 8)
 
     from ..configs.registry import get_config, get_smoke_config
     from ..data.pipeline import DataConfig
     from ..dist.collectives import SyncConfig
-    from ..launch.mesh import make_small_mesh
     from ..optim.adamw import AdamWConfig
     from ..train.train_step import TrainConfig
     from ..train.trainer import Trainer, TrainerConfig
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    axes = ("pod", "data", "model")[-len(shape):]
-    mesh = make_small_mesh(shape, axes)
+    if shape:
+        mesh = make_mesh(shape, ("pod", "data", "model")[-len(shape):])
+    else:
+        mesh = default_mesh()
     tcfg = TrainConfig(
         sync=SyncConfig(strategy=args.sync, density=args.density,
                         chunk=2048, min_leaf_size=4096),

@@ -177,7 +177,11 @@ def flash_attention(
         m0 = jnp.full((b, hkv, g, q_chunk), _NEG, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, q_chunk), jnp.float32)
         a0 = jnp.zeros((b, hkv, g, q_chunk, dv), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_block, (m0, l0, a0), jnp.arange(nk))
+        # rematerialized: the backward pass recomputes each block's scores
+        # instead of keeping all (Sq x Sk) of them alive
+        (m, l, acc), _ = jax.lax.scan(
+            jax.checkpoint(kv_block), (m0, l0, a0), jnp.arange(nk)
+        )
         out = acc / jnp.maximum(l[..., None], 1e-30)
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, q_chunk, hq, dv)
         return carry, out.astype(q.dtype)

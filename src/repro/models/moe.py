@@ -70,12 +70,7 @@ def moe_apply(
 
     e = p["wi"].shape[0]
     ctx = dist_context.current()
-    if (
-        not return_aux
-        and ctx is not None
-        and ctx.model_size > 1
-        and ctx.supports_manual_subregions
-    ):
+    if not return_aux and ctx is not None and ctx.model_size > 1:
         return _moe_apply_manual_ep(p, x, top_k=top_k,
                                     capacity_factor=capacity_factor, ctx=ctx)
     return _moe_apply_dense_dispatch(

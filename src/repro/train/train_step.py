@@ -4,14 +4,13 @@ The step is built once per (arch, shape, mesh, strategy) cell.  Model
 compute always runs under GSPMD (``jax.jit`` + sharding constraints): FSDP
 over ``data`` and tensor parallelism over ``model`` inside a pod.  The pod
 (WAN-analogue) boundary is owned by the GeoCoCo communicator: the gradient
-exchange runs in a fully-manual ``shard_map`` over the whole mesh, where
+exchange runs in a ``shard_map`` that is manual over ``pod`` only (the
+``data`` / ``model`` axes stay with GSPMD inside it), where
 ``repro.dist.collectives.sync_gradients`` resolves the configured strategy
 through the two-plane registry.  This split — GSPMD inside the pod, an
 explicit collective program across pods — mirrors the paper's architecture
 (intra-group transfers are cheap and automatic; the inter-group exchange is
-planned) and is also the only layering XLA's CPU partitioner executes
-reliably (partial-auto manual regions CHECK-fail; see
-``repro.dist.compat``).
+planned).
 
 ``input_specs`` returns ShapeDtypeStruct stand-ins for every model input, so
 the multi-pod dry-run lowers and compiles with zero allocation.
@@ -27,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig, ShapeSpec
-from ..dist import compat
 from ..dist.collectives import SyncConfig, sync_gradients
 from ..dist.sharding import param_shardings, param_specs
 from ..models.model import forward, init_cache, init_params
@@ -252,17 +250,15 @@ def loss_fn(cfg: ModelConfig, params, batch, compute_dtype=jnp.bfloat16,
 
 
 # ---------------------------------------------------------------------------
-# pod-boundary gradient sync (fully-manual shard_map region)
+# pod-boundary gradient sync (shard_map manual over `pod`)
 # ---------------------------------------------------------------------------
 
 
 def _strip_auto_axes(spec: P) -> P:
     """Drop non-``pod`` mesh axes from a spec.
 
-    Under native partial-auto shard_map (``axis_names={"pod"}`` on modern
-    JAX) the in/out specs may only mention the manual axis — ``data`` /
-    ``model`` sharding stays with GSPMD.  The fully-manual 0.4.x lowering
-    needs the complete specs instead.
+    In a shard_map manual over ``pod`` only, the in/out specs may mention
+    only the manual axis — ``data`` / ``model`` sharding stays with GSPMD.
     """
     out = []
     for part in spec:
@@ -281,30 +277,29 @@ def _make_pod_sync(mesh: Mesh, tcfg: TrainConfig, p_spec, *, with_residuals: boo
     Gradients enter at their parameter partitioning (``p_spec``); each
     device holds its FSDP/TP shard and exchanges it across the ``pod`` axis
     under the configured strategy.  Residual state (geococo error feedback)
-    is carried at the same partitioning.  On the 0.4.x toolchain the region
-    is fully manual (complete specs); on a native partial-auto JAX only the
-    pod components survive in the specs.
+    is carried at the same partitioning.  Only the pod components of the
+    specs survive; GSPMD keeps the in-pod partitioning.
     """
     n_pods = mesh.shape.get("pod", 1)
-    if compat.has_partial_auto():
-        p_spec = jax.tree.map(_strip_auto_axes, p_spec)
+    p_spec = jax.tree.map(_strip_auto_axes, p_spec)
 
     if with_residuals:
 
         def body(g, r):
             return sync_gradients(g, r, tcfg.sync, axis="pod", n_pods=n_pods)
 
-        return compat.shard_map(
-            body, mesh,
+        return jax.shard_map(
+            body, mesh=mesh,
             in_specs=(p_spec, p_spec), out_specs=(p_spec, p_spec),
-            axis_names={"pod"},
+            axis_names={"pod"}, check_vma=False,
         )
 
     def body(g):
         return sync_gradients(g, None, tcfg.sync, axis="pod", n_pods=n_pods)[0]
 
-    return compat.shard_map(
-        body, mesh, in_specs=(p_spec,), out_specs=p_spec, axis_names={"pod"},
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(p_spec,), out_specs=p_spec,
+        axis_names={"pod"}, check_vma=False,
     )
 
 

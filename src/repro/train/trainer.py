@@ -23,19 +23,18 @@ typed payload and bypassed the strategy registry.  Pass ``control=`` a
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .. import checkpoint as _ckpt_pkg  # noqa: F401  (namespace)
 from ..checkpoint.checkpoint import latest_step, restore, save, save_async
 from ..configs.base import ModelConfig
 from ..data.pipeline import DataConfig, SyntheticLM
-from ..dist.collectives import SyncConfig
 from ..models.model import init_params
 from ..optim.adamw import adamw_init
 from .train_step import TrainConfig, build_train_step
@@ -133,15 +132,15 @@ class Trainer:
         self._pending_save = None
         self.history: list[dict[str, float]] = []
 
-        self.params = init_params(model_cfg, jax.random.PRNGKey(run_cfg.seed))
-        self.params = jax.tree.map(
-            lambda p: p.astype(tcfg.param_dtype), self.params
+        # created in place at their shardings: nothing is built whole on
+        # one device and resharded afterwards
+        sh = self.shardings
+        init = jax.jit(
+            functools.partial(_init_state, model_cfg, tcfg),
+            out_shardings=(sh["params"], sh["opt"], sh["residuals"]),
         )
-        self.opt_state = adamw_init(self.params, tcfg.optim)
-        self.residuals = (
-            jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), self.params)
-            if tcfg.sync.needs_residuals
-            else None
+        self.params, self.opt_state, self.residuals = init(
+            jax.random.PRNGKey(run_cfg.seed)
         )
         self.step_idx = 0
         self._step_fn = None
@@ -265,6 +264,19 @@ class Trainer:
         if self._pending_save is not None:
             self._pending_save.join()
         return self.history
+
+
+def _init_state(model_cfg: ModelConfig, tcfg: TrainConfig, key):
+    """(params, optimizer state, residuals) of a fresh run."""
+    params = jax.tree.map(
+        lambda p: p.astype(tcfg.param_dtype), init_params(model_cfg, key)
+    )
+    residuals = (
+        jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        if tcfg.sync.needs_residuals
+        else None
+    )
+    return params, adamw_init(params, tcfg.optim), residuals
 
 
 class FaultInjected(RuntimeError):

@@ -258,3 +258,23 @@ def test_wkv6_chunked_property_sweep():
                                    rtol=5e-4, atol=5e-4)
 
     prop()
+
+
+# ---------------------------------------------------------------------------
+# interpret mode: only ever on the CPU backend
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,requested,expected", [
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, requested, expected):
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    if expected is ValueError:
+        with pytest.raises(ValueError, match="interpret"):
+            kernels.interpret_mode(requested)
+    else:
+        assert kernels.interpret_mode(requested) is expected
