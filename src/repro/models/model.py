@@ -14,6 +14,7 @@ Public API:
 
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 from typing import Any
@@ -41,6 +42,9 @@ from .layers import (
 )
 
 __all__ = ["init_params", "forward", "init_cache", "param_count", "num_params"]
+
+# mixers whose work the "attn" named scope covers (the profile's attention)
+_ATTN_MIXERS = ("attn", "attn_local", "attn_cross", "mla")
 
 
 # ---------------------------------------------------------------------------
@@ -126,48 +130,51 @@ def _block_apply(
     new_cache = cache
     hd = cfg.resolved_head_dim
 
-    if block.mixer in ("attn", "attn_local"):
-        window = cfg.local_window if block.mixer == "attn_local" else 0
-        y, new_attn_cache = gqa_apply(
-            p["mixer"], h,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=hd,
-            causal=cfg.causal, window=window, rope_theta=cfg.rope_theta,
-            cache=cache,
-        )
-        if cache is not None:
-            new_cache = new_attn_cache
-    elif block.mixer == "attn_cross":
-        assert img_ctx is not None, "cross-attention block needs image context"
-        y, _ = gqa_apply(
-            p["mixer"], h,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=hd,
-            causal=False, rope_theta=cfg.rope_theta, kv_source=img_ctx,
-        )
-        if cache is not None:
-            new_cache = {"len": cache["len"] + x.shape[1]}
-    elif block.mixer == "mla":
-        y, new_mla_cache = mla_mod.mla_apply(
-            p["mixer"], h, n_heads=cfg.n_heads, mla=cfg.mla,
-            causal=cfg.causal, rope_theta=cfg.rope_theta, cache=cache,
-        )
-        if cache is not None:
-            new_cache = new_mla_cache
-    elif block.mixer == "rwkv":
-        y, new_t = rwkv_mod.rwkv_tmix_apply(
-            p["mixer"], h, head_dim=cfg.rwkv_head_dim,
-            state=cache["tmix"] if cache is not None else None,
-        )
-        if cache is not None:
-            new_cache = dict(cache)
-            new_cache["tmix"] = new_t
-    elif block.mixer == "rglru":
-        y, new_r = rglru_mod.rglru_block_apply(
-            p["mixer"], h, state=cache if cache is not None else None
-        )
-        if cache is not None:
-            new_cache = new_r
-    else:
-        raise ValueError(block.mixer)
+    scope = (jax.named_scope("attn") if block.mixer in _ATTN_MIXERS
+             else contextlib.nullcontext())
+    with scope:
+        if block.mixer in ("attn", "attn_local"):
+            window = cfg.local_window if block.mixer == "attn_local" else 0
+            y, new_attn_cache = gqa_apply(
+                p["mixer"], h,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=hd,
+                causal=cfg.causal, window=window, rope_theta=cfg.rope_theta,
+                cache=cache,
+            )
+            if cache is not None:
+                new_cache = new_attn_cache
+        elif block.mixer == "attn_cross":
+            assert img_ctx is not None, "cross-attention block needs image context"
+            y, _ = gqa_apply(
+                p["mixer"], h,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=hd,
+                causal=False, rope_theta=cfg.rope_theta, kv_source=img_ctx,
+            )
+            if cache is not None:
+                new_cache = {"len": cache["len"] + x.shape[1]}
+        elif block.mixer == "mla":
+            y, new_mla_cache = mla_mod.mla_apply(
+                p["mixer"], h, n_heads=cfg.n_heads, mla=cfg.mla,
+                causal=cfg.causal, rope_theta=cfg.rope_theta, cache=cache,
+            )
+            if cache is not None:
+                new_cache = new_mla_cache
+        elif block.mixer == "rwkv":
+            y, new_t = rwkv_mod.rwkv_tmix_apply(
+                p["mixer"], h, head_dim=cfg.rwkv_head_dim,
+                state=cache["tmix"] if cache is not None else None,
+            )
+            if cache is not None:
+                new_cache = dict(cache)
+                new_cache["tmix"] = new_t
+        elif block.mixer == "rglru":
+            y, new_r = rglru_mod.rglru_block_apply(
+                p["mixer"], h, state=cache if cache is not None else None
+            )
+            if cache is not None:
+                new_cache = new_r
+        else:
+            raise ValueError(block.mixer)
     x = x + y
 
     if block.ffn == "none":
@@ -274,10 +281,11 @@ def forward(
     ac = act_constrain if act_constrain is not None else (lambda x: x)
 
     if cfg.frontend == "token":
-        if embed_fn is not None:
-            x = embed_fn(params["embed"], batch["tokens"], compute_dtype)
-        else:
-            x = embed_apply(params["embed"], batch["tokens"], dtype=compute_dtype)
+        with jax.named_scope("embed"):
+            if embed_fn is not None:
+                x = embed_fn(params["embed"], batch["tokens"], compute_dtype)
+            else:
+                x = embed_apply(params["embed"], batch["tokens"], dtype=compute_dtype)
     else:
         x = dense_apply(params["embed_proj"], batch["embeds"].astype(compute_dtype))
     img_ctx = batch.get("img")
@@ -320,11 +328,12 @@ def forward(
         if cache is not None:
             new_cache["suffix"].append(nc)
 
-    x = rmsnorm_apply(params["final_norm"], ac(x), eps=cfg.norm_eps)
-    if "lm_head" in params:
-        logits = dense_apply(params["lm_head"], x)
-    else:
-        logits = unembed_apply(params["embed"], x)
+    with jax.named_scope("logits"):
+        x = rmsnorm_apply(params["final_norm"], ac(x), eps=cfg.norm_eps)
+        if "lm_head" in params:
+            logits = dense_apply(params["lm_head"], x)
+        else:
+            logits = unembed_apply(params["embed"], x)
     if cache is not None:
         new_cache["prefix"] = tuple(new_cache["prefix"])
         new_cache["suffix"] = tuple(new_cache["suffix"])

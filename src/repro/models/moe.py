@@ -18,7 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .layers import Params, dense_init
+from .layers import Params, dense_apply, dense_init
 
 __all__ = ["moe_init", "moe_apply"]
 
@@ -134,38 +134,42 @@ def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
         lo = off[0]
         tl = xf.shape[0]
 
-        logits = (xf32 @ router_w).astype(f32)
-        probs = jax.nn.softmax(logits, axis=-1)                  # (Tl, E)
-        gate_vals, expert_idx = jax.lax.top_k(probs, top_k)      # (Tl, K)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9
-        )
-        onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)
-        pos_in_expert = (
-            jnp.cumsum(onehot.reshape(tl * top_k, e), axis=0)
-            * onehot.reshape(tl * top_k, e)
-        )
-        pos = (pos_in_expert.max(axis=-1) - 1).reshape(tl, top_k)
-        keep = pos < capacity
-        is_local = (expert_idx >= lo) & (expert_idx < lo + e_local)
-        keep_l = keep & is_local
-        le = jnp.where(is_local, expert_idx - lo, 0)             # (Tl, K)
-        pos_c = jnp.where(keep_l, pos, capacity - 1)
+        with jax.named_scope("moe_router"):
+            logits = (xf32 @ router_w).astype(f32)
+            probs = jax.nn.softmax(logits, axis=-1)                  # (Tl, E)
+            gate_vals, expert_idx = jax.lax.top_k(probs, top_k)      # (Tl, K)
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(-1, keepdims=True), 1e-9
+            )
+            onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)
+            pos_in_expert = (
+                jnp.cumsum(onehot.reshape(tl * top_k, e), axis=0)
+                * onehot.reshape(tl * top_k, e)
+            )
+            pos = (pos_in_expert.max(axis=-1) - 1).reshape(tl, top_k)
+            keep = pos < capacity
+            is_local = (expert_idx >= lo) & (expert_idx < lo + e_local)
+            keep_l = keep & is_local
+            le = jnp.where(is_local, expert_idx - lo, 0)             # (Tl, K)
+            pos_c = jnp.where(keep_l, pos, capacity - 1)
 
-        buf = jnp.zeros((e_local, capacity, d), compute_dtype)
-        for j in range(top_k):  # top_k scatters — no (T*k, d) repeat
-            src = xf * keep_l[:, j, None].astype(compute_dtype)
-            buf = buf.at[le[:, j], pos_c[:, j]].add(src)
-        h = jnp.einsum("ecd,edf->ecf", buf, wi_)
-        g = jnp.einsum("ecd,edf->ecf", buf, wg_)
-        y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, wo_)
+        with jax.named_scope("moe_dispatch"):
+            buf = jnp.zeros((e_local, capacity, d), compute_dtype)
+            for j in range(top_k):  # top_k scatters — no (T*k, d) repeat
+                src = xf * keep_l[:, j, None].astype(compute_dtype)
+                buf = buf.at[le[:, j], pos_c[:, j]].add(src)
+        with jax.named_scope("moe_experts"):
+            h = jnp.einsum("ecd,edf->ecf", buf, wi_)
+            g = jnp.einsum("ecd,edf->ecf", buf, wg_)
+            y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, wo_)
 
-        out = jnp.zeros((tl, d), f32)
-        for j in range(top_k):
-            got = y[le[:, j], pos_c[:, j]].astype(f32)
-            w_j = (gate_vals[:, j] * keep_l[:, j]).astype(f32)
-            out = out + got * w_j[:, None]
-        return jax.lax.psum(out, "model")
+        with jax.named_scope("moe_combine"):
+            out = jnp.zeros((tl, d), f32)
+            for j in range(top_k):
+                got = y[le[:, j], pos_c[:, j]].astype(f32)
+                w_j = (gate_vals[:, j] * keep_l[:, j]).astype(f32)
+                out = out + got * w_j[:, None]
+            return jax.lax.psum(out, "model")
 
     manual = {"model"} | ({"data"} if (shard_tokens or fsdp_w) else set())
     tspec = P("data") if shard_tokens else P()
@@ -187,12 +191,14 @@ def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
     ).astype(x.dtype)
 
     if "shared" in p:
-        sh = p["shared"]
-        from .layers import dense_apply
-
-        hs = jax.nn.silu(dense_apply(sh["wg"], xf)) * dense_apply(sh["wi"], xf)
-        out = out + dense_apply(sh["wo"], hs)
+        with jax.named_scope("moe_experts"):
+            out = out + _shared_experts(p["shared"], xf)
     return out.reshape(b, s, d)
+
+
+def _shared_experts(sh: Params, xf: jnp.ndarray) -> jnp.ndarray:
+    hs = jax.nn.silu(dense_apply(sh["wg"], xf)) * dense_apply(sh["wi"], xf)
+    return dense_apply(sh["wo"], hs)
 
 
 def _moe_apply_dense_dispatch(
@@ -208,43 +214,44 @@ def _moe_apply_dense_dispatch(
     t = b * s
     xf = x.reshape(t, d)
 
-    logits = (xf @ p["router"]["w"].astype(jnp.float32)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, top_k)         # (T, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_router"):
+        logits = (xf @ p["router"]["w"].astype(jnp.float32)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
+        gate_vals, expert_idx = jax.lax.top_k(probs, top_k)         # (T, K)
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
 
-    capacity = max(1, int(capacity_factor * top_k * t / e))
-    # position of each (token, k) assignment within its expert's queue
-    onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)     # (T, K, E)
-    flat_oh = onehot.reshape(t * top_k, e)
-    pos_in_expert = jnp.cumsum(flat_oh, axis=0) * flat_oh       # rank+1 where assigned
-    pos = (pos_in_expert.max(axis=-1) - 1).reshape(t, top_k)    # (T, K)
-    keep = pos < capacity
+        capacity = max(1, int(capacity_factor * top_k * t / e))
+        # position of each (token, k) assignment within its expert's queue
+        onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)     # (T, K, E)
+        flat_oh = onehot.reshape(t * top_k, e)
+        pos_in_expert = jnp.cumsum(flat_oh, axis=0) * flat_oh       # rank+1 where assigned
+        pos = (pos_in_expert.max(axis=-1) - 1).reshape(t, top_k)    # (T, K)
+        keep = pos < capacity
 
     # dispatch: scatter token vectors into (E, C, d) buffers
-    buf = jnp.zeros((e, capacity, d), xf.dtype)
-    flat_e = expert_idx.reshape(-1)
-    flat_pos = jnp.where(keep, pos, capacity - 1).reshape(-1)   # clamp; masked below
-    flat_keep = keep.reshape(-1)
-    src = jnp.repeat(xf, top_k, axis=0) * flat_keep[:, None].astype(xf.dtype)
-    buf = buf.at[flat_e, flat_pos].add(src)
+    with jax.named_scope("moe_dispatch"):
+        buf = jnp.zeros((e, capacity, d), xf.dtype)
+        flat_e = expert_idx.reshape(-1)
+        flat_pos = jnp.where(keep, pos, capacity - 1).reshape(-1)   # clamp; masked below
+        flat_keep = keep.reshape(-1)
+        src = jnp.repeat(xf, top_k, axis=0) * flat_keep[:, None].astype(xf.dtype)
+        buf = buf.at[flat_e, flat_pos].add(src)
 
     # expert FFN: (E, C, d) x (E, d, f)
-    h = jnp.einsum("ecd,edf->ecf", buf, p["wi"].astype(xf.dtype))
-    g = jnp.einsum("ecd,edf->ecf", buf, p["wg"].astype(xf.dtype))
-    y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, p["wo"].astype(xf.dtype))
+    with jax.named_scope("moe_experts"):
+        h = jnp.einsum("ecd,edf->ecf", buf, p["wi"].astype(xf.dtype))
+        g = jnp.einsum("ecd,edf->ecf", buf, p["wg"].astype(xf.dtype))
+        y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, p["wo"].astype(xf.dtype))
 
     # combine: gather each assignment's output, weight by gate
-    out_tok = y[flat_e, flat_pos]                               # (T*K, d)
-    out_tok = out_tok * (gate_vals.reshape(-1) * flat_keep).astype(xf.dtype)[:, None]
-    out = out_tok.reshape(t, top_k, d).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        out_tok = y[flat_e, flat_pos]                               # (T*K, d)
+        out_tok = out_tok * (gate_vals.reshape(-1) * flat_keep).astype(xf.dtype)[:, None]
+        out = out_tok.reshape(t, top_k, d).sum(axis=1)
 
     if "shared" in p:
-        sh = p["shared"]
-        from .layers import dense_apply
-
-        hs = jax.nn.silu(dense_apply(sh["wg"], xf)) * dense_apply(sh["wi"], xf)
-        out = out + dense_apply(sh["wo"], hs)
+        with jax.named_scope("moe_experts"):
+            out = out + _shared_experts(p["shared"], xf)
 
     out = out.reshape(b, s, d)
     if not return_aux:

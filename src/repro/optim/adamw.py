@@ -57,6 +57,11 @@ def adamw_update(
     params: Any, grads: Any, state: dict, cfg: AdamWConfig
 ) -> tuple[Any, dict, dict]:
     """One optimizer step.  Returns (new_params, new_state, metrics)."""
+    with jax.named_scope("adam"):
+        return _adamw_update(params, grads, state, cfg)
+
+
+def _adamw_update(params, grads, state, cfg):
     step = state["step"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9))

@@ -245,8 +245,9 @@ def loss_fn(cfg: ModelConfig, params, batch, compute_dtype=jnp.bfloat16,
     logits, _ = forward(cfg, params, batch, compute_dtype=compute_dtype,
                         act_constrain=act_constrain)
     labels = batch["labels"]
-    lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0].mean()
+    with jax.named_scope("logits"):
+        lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0].mean()
 
 
 # ---------------------------------------------------------------------------
@@ -286,7 +287,8 @@ def _make_pod_sync(mesh: Mesh, tcfg: TrainConfig, p_spec, *, with_residuals: boo
     if with_residuals:
 
         def body(g, r):
-            return sync_gradients(g, r, tcfg.sync, axis="pod", n_pods=n_pods)
+            with jax.named_scope("pod_exchange"):
+                return sync_gradients(g, r, tcfg.sync, axis="pod", n_pods=n_pods)
 
         return jax.shard_map(
             body, mesh=mesh,
@@ -295,7 +297,8 @@ def _make_pod_sync(mesh: Mesh, tcfg: TrainConfig, p_spec, *, with_residuals: boo
         )
 
     def body(g):
-        return sync_gradients(g, None, tcfg.sync, axis="pod", n_pods=n_pods)[0]
+        with jax.named_scope("pod_exchange"):
+            return sync_gradients(g, None, tcfg.sync, axis="pod", n_pods=n_pods)[0]
 
     return jax.shard_map(
         body, mesh=mesh, in_specs=(p_spec,), out_specs=p_spec,

@@ -127,10 +127,12 @@ class Trainer:
         self.control = control
         self.network_events: list[Any] = []
         self.sync_rebuilds = 0
+        self.compiles = 0    # freshly built steps called (each may compile)
+        self.rollbacks = 0   # recoverable faults rolled back to a checkpoint
         if control is not None:
             control.subscribe(self._on_network_event)
         self._pending_save = None
-        self.history: list[dict[str, float]] = []
+        self.history: list[dict[str, Any]] = []
 
         # created in place at their shardings: nothing is built whole on
         # one device and resharded afterwards
@@ -209,53 +211,76 @@ class Trainer:
         return self._step_fn
 
     def run(self, *, fault_injector: Callable[[int], None] | None = None):
+        """Train until ``run_cfg.steps``.  Each iteration is a profiler step
+        ``train`` holding host spans ``trainer.data``, ``trainer.put``,
+        ``trainer.compile`` (first call of a freshly built step) or
+        ``trainer.step``, ``trainer.wait``, ``trainer.control`` and
+        ``trainer.checkpoint``, each tagged with its step."""
         cfg = self.run_cfg
-        start = self.step_idx
+        span = jax.profiler.TraceAnnotation
         while self.step_idx < cfg.steps:
-            batch = {
-                k: jnp.asarray(v)
-                for k, v in self.data.batch(self.step_idx).items()
-            }
-            step = self._build(batch)
-            t0 = time.perf_counter()
-            try:
-                if fault_injector is not None:
-                    fault_injector(self.step_idx)
-                out = step(self.params, self.opt_state, self.residuals, batch)
-                self.params, self.opt_state, self.residuals, metrics = out
-                jax.block_until_ready(metrics["loss"])
-            except _RECOVERABLE as e:  # device failure: roll back + replay
-                resumed = self.maybe_resume()
-                if not resumed:
-                    raise
-                self._step_fn = None  # rebuild on (possibly new) topology
-                continue
-            dt = time.perf_counter() - t0
-            self.step_idx += 1
-            rec = {
-                "step": self.step_idx,
-                "loss": float(metrics["loss"]),
-                "grad_norm": float(metrics["grad_norm"]),
-                "dt": dt,
-            }
-            self.history.append(rec)
-            if (
-                self.control is not None
-                and self.control.view is not None
-                and self.step_idx % max(1, cfg.control_every) == 0
-            ):
-                self.control.step()  # probe -> damped replan -> events
-            if self.monitor.observe(dt):
-                if self.control is not None:
-                    # sustained step-time degradation: event-driven replan,
-                    # effective immediately (not at the next observation)
-                    self.control.force_replan(
-                        reason=f"straggler@step{self.step_idx}"
-                    )
-                if self.on_straggler is not None:
-                    self.on_straggler(self)
-            if cfg.ckpt_dir and self.step_idx % cfg.ckpt_every == 0:
-                self.save_ckpt()
+            n = self.step_idx
+            with jax.profiler.StepTraceAnnotation("train", step_num=n):
+                t_data = time.perf_counter()
+                with span("trainer.data", step=n):
+                    host_batch = self.data.batch(n)
+                with span("trainer.put", step=n):
+                    batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+                data_s = time.perf_counter() - t_data
+                fresh = self._step_fn is None
+                step = self._build(batch)
+                t0 = time.perf_counter()
+                try:
+                    if fault_injector is not None:
+                        fault_injector(n)
+                    with span("trainer.compile" if fresh else "trainer.step", step=n):
+                        out = step(self.params, self.opt_state, self.residuals, batch)
+                    self.params, self.opt_state, self.residuals, metrics = out
+                    with span("trainer.wait", step=n):
+                        jax.block_until_ready(metrics["loss"])
+                        dt = time.perf_counter() - t0
+                        loss = float(metrics["loss"])
+                        grad_norm = float(metrics["grad_norm"])
+                except _RECOVERABLE:  # device failure: roll back + replay
+                    resumed = self.maybe_resume()
+                    if not resumed:
+                        raise
+                    self._step_fn = None  # rebuild on (possibly new) topology
+                    self.rollbacks += 1
+                    continue
+                if fresh:
+                    self.compiles += 1
+                self.step_idx += 1
+                rec = {
+                    "step": self.step_idx,
+                    "loss": loss,
+                    "grad_norm": grad_norm,
+                    "dt": dt,
+                    "data_s": data_s,
+                    "compiled": fresh,
+                }
+                self.history.append(rec)
+                with span("trainer.control", step=n):
+                    if (
+                        self.control is not None
+                        and self.control.view is not None
+                        and self.step_idx % max(1, cfg.control_every) == 0
+                    ):
+                        self.control.step()  # probe -> damped replan -> events
+                    # a step that compiled says nothing about the device's pace
+                    if not fresh and self.monitor.observe(dt):
+                        if self.control is not None:
+                            # sustained step-time degradation: event-driven
+                            # replan, effective immediately (not at the next
+                            # observation)
+                            self.control.force_replan(
+                                reason=f"straggler@step{self.step_idx}"
+                            )
+                        if self.on_straggler is not None:
+                            self.on_straggler(self)
+                if cfg.ckpt_dir and self.step_idx % cfg.ckpt_every == 0:
+                    with span("trainer.checkpoint", step=n):
+                        self.save_ckpt()
             if self.step_idx % cfg.log_every == 0 or self.step_idx == cfg.steps:
                 print(
                     f"step {rec['step']:5d}  loss {rec['loss']:.4f}  "

@@ -80,6 +80,54 @@ def test_fault_injection_rolls_back_and_replays(tmp_path):
     assert fired["n"] == 1
     assert tr.step_idx == 8
     assert hist[-1]["loss"] < hist[0]["loss"]
+    # the rollback rebuilt the step: step 5 (the first replayed) compiled
+    assert (tr.rollbacks, tr.compiles) == (1, 2)
+    assert [h["step"] for h in hist if h["compiled"]] == [1, 5]
+
+
+def test_history_marks_the_compiled_step(tmp_path):
+    tr = _mk_trainer(tmp_path, steps=4)
+    hist = tr.run()
+    assert [h["compiled"] for h in hist] == [True, False, False, False]
+    assert (tr.compiles, tr.rollbacks) == (1, 0)
+    assert all(h["data_s"] > 0 for h in hist)
+
+
+def test_straggler_monitor_skips_the_compiled_step(tmp_path):
+    tr = _mk_trainer(tmp_path, steps=3)
+    dts = [h["dt"] for h in tr.run()]
+    # seeded by step 2, then one EWMA update with step 3; step 1 compiled
+    a = tr.monitor.alpha
+    assert tr.monitor.ewma == pytest.approx((1 - a) * dts[1] + a * dts[2])
+
+
+def test_profile_holds_the_trainer_spans(tmp_path):
+    """Each step is a ``train`` profiler step holding its ``trainer.*``
+    spans on the host plane, tagged with the step."""
+    from jax.profiler import ProfileData
+
+    tr = _mk_trainer(tmp_path, steps=2)
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        tr.run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = (tmp_path / "trace").glob("plugins/profile/*/*.xplane.pb")
+    events = [e for p in ProfileData.from_file(str(path)).planes
+              if p.name.startswith("/host:") for line in p.lines for e in line.events]
+    steps = {dict(e.stats)["step_num"]: e for e in events if e.name == "train"}
+    assert sorted(steps) == [0, 1]
+    spans = {}
+    for e in events:
+        if e.name.startswith("trainer."):
+            n = dict(e.stats)["step"]
+            t = steps[n]
+            assert t.start_ns <= e.start_ns
+            assert e.start_ns + e.duration_ns <= t.start_ns + t.duration_ns
+            spans.setdefault(n, set()).add(e.name)
+    want = {"trainer.data", "trainer.put", "trainer.wait", "trainer.control"}
+    assert spans[0] >= want | {"trainer.compile"} and "trainer.step" not in spans[0]
+    assert spans[1] >= want | {"trainer.step"} and "trainer.compile" not in spans[1]
 
 
 def test_elastic_reshard_across_meshes(tmp_path):
