@@ -31,25 +31,37 @@ class DataConfig:
 
 class SyntheticLM:
     """Markov-ish synthetic LM stream: next token either copies a recent
-    token (learnable structure) or draws from a Zipfian unigram."""
+    token (learnable structure) or draws from a Zipfian unigram.
+
+    A fresh token is drawn by inverse-CDF sampling: one uniform from
+    ``rng.random`` looked up in the unigram's CDF, which is built once
+    here.  That is the draw ``Generator.choice(vocab, p=p)`` makes, with
+    the CDF computed by the same float64 operations, so the stream is
+    consumed in the same order and the tokens are those ``choice`` gives,
+    without its O(vocab) work per call.  A batch is still a pure function
+    of (seed, step)."""
 
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
         ranks = np.arange(1, cfg.vocab_size + 1, dtype=np.float64)
         p = ranks ** (-cfg.theta)
         self._p = p / p.sum()
+        cdf = self._p.cumsum()
+        cdf /= cdf[-1]
+        self._cdf = cdf
 
     def batch(self, step: int) -> dict[str, np.ndarray]:
         cfg = self.cfg
         rng = np.random.default_rng((cfg.seed << 20) ^ step)
         b, s = cfg.global_batch, cfg.seq_len
+        rows = np.arange(b)
         toks = np.empty((b, s + 1), dtype=np.int32)
-        toks[:, 0] = rng.choice(cfg.vocab_size, size=b, p=self._p)
+        toks[:, 0] = self._cdf.searchsorted(rng.random(b), side="right")
         for t in range(1, s + 1):
             copy = rng.random(b) < cfg.copy_prob
             back = rng.integers(1, min(t, cfg.window) + 1, size=b)
-            copied = toks[np.arange(b), t - back]
-            fresh = rng.choice(cfg.vocab_size, size=b, p=self._p)
+            copied = toks[rows, t - back]
+            fresh = self._cdf.searchsorted(rng.random(b), side="right")
             toks[:, t] = np.where(copy & (t > 1), copied, fresh)
         return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
