@@ -196,7 +196,8 @@ def compile_step_shapes(cfg, mesh, tcfg, batch: int):
     batch_abs = ts.input_specs(cfg, ShapeSpec("smoke", SEQ, batch, "train"))
     return compile_aot(
         make_jit(batch_abs), ts.abstract_params(cfg, tcfg.param_dtype),
-        ts.abstract_opt_state(cfg, tcfg), ts.abstract_residuals(cfg, tcfg),
+        ts.abstract_opt_state(cfg, tcfg),
+        ts.abstract_residuals(cfg, tcfg, mesh.shape.get("pod", 1)),
         batch_abs,
     )
 

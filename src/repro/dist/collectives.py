@@ -24,13 +24,16 @@ resolve the *same names* — ``flat`` / ``hier`` / ``geococo``.
 cross-check against the WAN simulator and against bytes actually moved by
 :func:`sync_gradients`.
 
-Deployment note: on a single-controller runtime (this container) the
-backward pass has already all-reduced gradients over every mesh axis by the
-time ``sync_gradients`` runs, so the pod exchange operates on pod-identical
-inputs — ``pmean`` is then numerically a no-op while the ``geococo``
-sparsification still changes the update exactly as on a real multi-pod
-deployment.  On a multi-controller deployment the same collectives perform
-the real exchange; the wire model is identical either way.
+Deployment note: the train step (``repro.train.train_step``) takes each
+pod's loss and gradient from that pod's own rows of the batch, inside a
+``shard_map`` manual over ``pod``, so the gradients reaching
+``sync_gradients`` differ by pod as they do between regions, and nothing
+else crosses the pod axis but the scalar loss mean.  ``geococo``'s
+error-feedback residuals therefore differ by pod too.  The exchange still
+moves dense bytes: :func:`chunked_topk_exchange` masks the unsent entries
+to zero and ``pmean``s the whole array, so the sparsification changes the
+update, and :func:`estimate_sync_bytes` gives the (value, index) bytes a
+sparse transport would move, not what the collective moves today.
 """
 
 from __future__ import annotations
@@ -288,11 +291,15 @@ def sync_gradients(
     """Synchronize a gradient pytree across pods under ``cfg.strategy``.
 
     Must run where ``axis`` is a bound (manual) mesh axis when
-    ``n_pods > 1`` — e.g. inside a ``shard_map`` over the pod axis.  With a
-    single pod this is the identity (the input objects are returned
-    untouched).  ``leaf_specs`` is accepted for callers that track per-leaf
-    partitioning; the exchange itself operates on whatever slice of each
-    leaf the calling region holds.
+    ``n_pods > 1`` — e.g. inside a ``shard_map`` over the pod axis.
+    ``grads`` and ``residuals`` are this pod's own: its gradient from its
+    own rows of the batch, and its error-feedback state, which differs by
+    pod.  Every pod returns the same synced gradients (the mean over pods
+    of what each sent) and its own new residuals.  The collectives move
+    dense arrays (unsent entries as zeros).  With a single pod this is the
+    identity (the input objects are returned untouched).  ``leaf_specs`` is
+    accepted for callers that track per-leaf partitioning; the exchange
+    itself operates on whatever slice of each leaf the calling region holds.
 
     Returns ``(synced_grads, new_residuals)``.  ``new_residuals`` is ``None``
     whenever ``residuals`` is ``None`` and the strategy carries no state.

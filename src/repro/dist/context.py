@@ -44,10 +44,12 @@ class DistContext:
 
     def shard_map(self, fn, *, in_specs, out_specs, axis_names):
         """Manual subregion over ``axis_names`` of the context mesh; the
-        other axes stay with GSPMD."""
+        other axes stay with GSPMD.  Inside an enclosing manual region (the
+        train step's pod region) it nests on that region's mesh."""
+        mesh = jax.sharding.get_abstract_mesh()
         return jax.shard_map(
-            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(axis_names), check_vma=False,
+            fn, mesh=self.mesh if mesh.empty else mesh, in_specs=in_specs,
+            out_specs=out_specs, axis_names=set(axis_names), check_vma=False,
         )
 
 

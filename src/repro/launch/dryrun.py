@@ -84,7 +84,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, strategy: str,
         lowered = step.lower(
             abstract_params(cfg, tcfg.param_dtype),
             abstract_opt_state(cfg, tcfg),
-            abstract_residuals(cfg, tcfg),
+            abstract_residuals(cfg, tcfg, mesh_shape.get("pod", 1)),
             batch,
         )
     elif shape.kind == "prefill":

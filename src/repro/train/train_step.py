@@ -3,14 +3,20 @@
 The step is built once per (arch, shape, mesh, strategy) cell.  Model
 compute always runs under GSPMD (``jax.jit`` + sharding constraints): FSDP
 over ``data`` and tensor parallelism over ``model`` inside a pod.  The pod
-(WAN-analogue) boundary is owned by the GeoCoCo communicator: the gradient
-exchange runs in a ``shard_map`` that is manual over ``pod`` only (the
-``data`` / ``model`` axes stay with GSPMD inside it), where
-``repro.dist.collectives.sync_gradients`` resolves the configured strategy
-through the two-plane registry.  This split — GSPMD inside the pod, an
-explicit collective program across pods — mirrors the paper's architecture
-(intra-group transfers are cheap and automatic; the inter-group exchange is
-planned).
+(WAN-analogue) boundary is owned by the GeoCoCo communicator.  With more
+than one pod, the forward and backward passes run in a ``shard_map`` that
+is manual over ``pod`` only (the ``data`` / ``model`` axes stay with GSPMD
+inside it): each pod takes the loss and gradient of its own share of the
+batch, as a region computes on its own sequences, and
+``repro.dist.collectives.sync_gradients`` then exchanges them under the
+configured strategy, resolved through the two-plane registry.  That
+exchange and the scalar loss mean are the only traffic over ``pod``.  This
+split — GSPMD inside the pod, an explicit collective program across pods —
+mirrors the paper's architecture (intra-group transfers are cheap and
+automatic; the inter-group exchange is planned).
+
+Error-feedback residuals (``geococo``) differ by pod: with ``n_pods > 1``
+each leaf carries a leading axis of size ``n_pods`` sharded over ``pod``.
 
 ``input_specs`` returns ShapeDtypeStruct stand-ins for every model input, so
 the multi-pod dry-run lowers and compiles with zero allocation.
@@ -94,11 +100,16 @@ def abstract_opt_state(cfg: ModelConfig, tcfg: TrainConfig):
     return jax.eval_shape(lambda p: adamw_init(p, tcfg.optim), params)
 
 
-def abstract_residuals(cfg: ModelConfig, tcfg: TrainConfig):
+def abstract_residuals(cfg: ModelConfig, tcfg: TrainConfig, n_pods: int = 1):
+    """Error-feedback state, ``None`` for a strategy without it.  Each pod
+    keeps its own: with ``n_pods > 1`` every leaf gains a leading pod axis."""
     if not tcfg.sync.needs_residuals:
         return None
+    lead = (n_pods,) if n_pods > 1 else ()
     params = abstract_params(cfg, tcfg.param_dtype)
-    return jax.tree.map(lambda l: jax.ShapeDtypeStruct(l.shape, jnp.float32), params)
+    return jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(lead + l.shape, jnp.float32), params
+    )
 
 
 def abstract_cache(cfg: ModelConfig, shape: ShapeSpec, dtype=jnp.bfloat16):
@@ -112,12 +123,13 @@ def abstract_cache(cfg: ModelConfig, shape: ShapeSpec, dtype=jnp.bfloat16):
 # ---------------------------------------------------------------------------
 
 
-def _fit_batch_axes(mesh: Mesh, dim: int) -> tuple[str, ...]:
+def _fit_batch_axes(mesh: Mesh, dim: int, *, over_pod: bool = True) -> tuple[str, ...]:
     """Largest prefix-combination of (pod, data) that divides ``dim``.
 
     A global_batch of 1 (long_500k single-request decode) replicates over the
-    batch axes; the model axis still shards the compute."""
-    cands = [("pod", "data"), ("data",), ("pod",)]
+    batch axes; the model axis still shards the compute.  ``over_pod=False``
+    leaves ``pod`` out, as inside the pod region, where it is manual."""
+    cands = [("pod", "data"), ("data",), ("pod",)] if over_pod else [("data",)]
     for axes in cands:
         if all(a in mesh.shape for a in axes):
             size = 1
@@ -179,13 +191,14 @@ def _constrain(tree, shardings):
     )
 
 
-def _constrain_batch(batch, mesh: Mesh):
-    """Pin the batch dim over the (pod, data) device axes inside the step."""
+def _constrain_batch(batch, mesh: Mesh, *, over_pod: bool = True):
+    """Pin the batch dim over the (pod, data) device axes inside the step
+    (over ``data`` alone with ``over_pod=False``)."""
 
     def one(x):
         if getattr(x, "ndim", 0) == 0:
             return x
-        axes = _fit_batch_axes(mesh, x.shape[0])
+        axes = _fit_batch_axes(mesh, x.shape[0], over_pod=over_pod)
         if not axes:
             return x
         spec = P(axes, *([None] * (x.ndim - 1)))
@@ -194,21 +207,23 @@ def _constrain_batch(batch, mesh: Mesh):
     return jax.tree.map(one, batch)
 
 
-def _act_constrain(mesh: Mesh, *, seq_parallel: bool = False):
+def _act_constrain(mesh: Mesh, *, seq_parallel: bool = False, over_pod: bool = True):
     """Residual-stream constraint at block boundaries.
 
     Batch over `data` (so GSPMD never resolves an FSDP weight/activation
-    conflict by replicating the batch).  ``seq_parallel`` additionally shards
-    the sequence dim over `model` (Megatron-style) — measured on this
-    container it triggers GSPMD resharding storms under the FSDP weight
-    gathers, so it stays off by default.
+    conflict by replicating the batch), and over `pod` unless
+    ``over_pod=False`` (inside the pod region).  ``seq_parallel``
+    additionally shards the sequence dim over `model` (Megatron-style) —
+    measured on this container it triggers GSPMD resharding storms under the
+    FSDP weight gathers, so it stays off by default.
     """
     dd = mesh.shape.get("data", 1)
     dm = mesh.shape.get("model", 1)
-    dp = mesh.shape.get("pod", 1)
+    dp = mesh.shape.get("pod", 1) if over_pod else 1
     if dd <= 1 and dm <= 1 and dp <= 1:
         return None
-    baxes = [a for a in ("pod", "data") if mesh.shape.get(a, 1) > 1]
+    baxes = [a for a in (("pod", "data") if over_pod else ("data",))
+             if mesh.shape.get(a, 1) > 1]
     bsize = 1
     for a in baxes:
         bsize *= mesh.shape[a]
@@ -241,17 +256,22 @@ def _act_constrain(mesh: Mesh, *, seq_parallel: bool = False):
 
 
 def loss_fn(cfg: ModelConfig, params, batch, compute_dtype=jnp.bfloat16,
-            act_constrain=None):
+            act_constrain=None, *, embed_fn=None, logp_constrain=None):
+    """Mean next-token cross-entropy.  ``embed_fn`` replaces the vocabulary
+    lookup (see ``forward``); ``logp_constrain`` pins the log-probabilities
+    before the label pick."""
     logits, _ = forward(cfg, params, batch, compute_dtype=compute_dtype,
-                        act_constrain=act_constrain)
+                        act_constrain=act_constrain, embed_fn=embed_fn)
     labels = batch["labels"]
     with jax.named_scope("logits"):
         lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        if logp_constrain is not None:
+            lp = logp_constrain(lp)
         return -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0].mean()
 
 
 # ---------------------------------------------------------------------------
-# pod-boundary gradient sync (shard_map manual over `pod`)
+# the pod region (shard_map manual over `pod`)
 # ---------------------------------------------------------------------------
 
 
@@ -272,36 +292,57 @@ def _strip_auto_axes(spec: P) -> P:
     return P(*out)
 
 
-def _make_pod_sync(mesh: Mesh, tcfg: TrainConfig, p_spec, *, with_residuals: bool):
-    """Wrap ``sync_gradients`` in a shard_map over the pod axis.
+def _whole_vocab_lookups(mesh: Mesh, ac) -> dict:
+    """``loss_fn`` arguments that keep the vocabulary whole at its two
+    gathers (the embedding lookup, the label pick), for the pod region.
 
-    Gradients enter at their parameter partitioning (``p_spec``); each
-    device holds its FSDP/TP shard and exchanges it across the ``pod`` axis
-    under the configured strategy.  Residual state (geococo error feedback)
-    is carried at the same partitioning.  Only the pod components of the
-    specs survive; GSPMD keeps the in-pod partitioning.
+    XLA's SPMD partitioner aborts (``ExpandDeviceGroupsWithIota``) when it
+    partitions a gather along a sharded operand dimension inside a region
+    manual over ``pod`` (seen with ``model`` > 1), so there the table is
+    gathered whole before the lookup and the log-probabilities keep only
+    their batch dimension split."""
+    whole = NamedSharding(mesh, P())
+
+    def embed(p, tokens, dtype):
+        table = jax.lax.with_sharding_constraint(p["table"].astype(dtype), whole)
+        return table[tokens]
+
+    return {"embed_fn": embed, "logp_constrain": ac}
+
+
+def _make_pod_step(mesh: Mesh, tcfg: TrainConfig, p_spec, loss_and_grads):
+    """Each pod's loss and gradient, then the exchange across pods.
+
+    ``loss_and_grads(params, batch)`` runs inside a ``shard_map`` manual
+    over ``pod``: it sees its pod's own rows of the batch and the parameters
+    at their in-pod partitioning (only the pod components of ``p_spec``
+    survive; GSPMD keeps FSDP/TP), so nothing crosses ``pod`` before
+    ``sync_gradients`` exchanges the gradients under the configured
+    strategy, in the ``pod_exchange`` scope.  Residuals carry a leading pod
+    axis, one slice per pod.  Returns ``step(params, batch, residuals) ->
+    (loss mean over pods, synced grads, new residuals)``.
     """
-    n_pods = mesh.shape.get("pod", 1)
+    n_pods = mesh.shape["pod"]
     p_spec = jax.tree.map(_strip_auto_axes, p_spec)
 
-    if with_residuals:
-
-        def body(g, r):
-            with jax.named_scope("pod_exchange"):
-                return sync_gradients(g, r, tcfg.sync, axis="pod", n_pods=n_pods)
-
-        return jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(p_spec, p_spec), out_specs=(p_spec, p_spec),
-            axis_names={"pod"}, check_vma=False,
-        )
-
-    def body(g):
+    def body(params, batch, residuals):
+        loss, grads = loss_and_grads(params, batch)
+        # the whole backward pass before any of the exchange: left free, the
+        # TPU scheduler overlaps the exchange's temporaries with it, and the
+        # 8-layer granite step on pod=2 x data=2 needs 15.81 GiB of a v5e's
+        # 15.75 (15.02 GiB with the barrier)
+        grads = jax.lax.optimization_barrier(grads)
+        res = None if residuals is None else jax.tree.map(lambda r: r[0], residuals)
         with jax.named_scope("pod_exchange"):
-            return sync_gradients(g, None, tcfg.sync, axis="pod", n_pods=n_pods)[0]
+            grads, res = sync_gradients(grads, res, tcfg.sync, axis="pod",
+                                        n_pods=n_pods)
+        if res is not None:
+            res = jax.tree.map(lambda r: r[None], res)
+        return jax.lax.pmean(loss, "pod"), grads, res
 
     return jax.shard_map(
-        body, mesh=mesh, in_specs=(p_spec,), out_specs=p_spec,
+        body, mesh=mesh,
+        in_specs=(p_spec, P("pod"), P("pod")), out_specs=(P(), p_spec, P("pod")),
         axis_names={"pod"}, check_vma=False,
     )
 
@@ -316,6 +357,10 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
 
     step(params, opt_state, residuals, batch) ->
         (params', opt_state', residuals', metrics)
+
+    With more than one pod each pod's gradient comes from its own rows of
+    the batch and crosses ``pod`` only through the exchange
+    (:func:`_make_pod_step`); the loss reported is the mean over pods.
     """
     n_pods = mesh.shape.get("pod", 1)
     p_abs = abstract_params(cfg, tcfg.param_dtype)
@@ -326,66 +371,60 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
         "v": p_shard,
         "step": NamedSharding(mesh, P()),
     }
-    res_abs = abstract_residuals(cfg, tcfg)
-    res_shard = p_shard if res_abs is not None else None
+    res_shard = None
+    if tcfg.sync.needs_residuals:
+        res_shard = p_shard if n_pods == 1 else jax.tree.map(
+            lambda s: NamedSharding(mesh, P("pod", *s)), p_spec)
 
-    ac = _act_constrain(mesh) if tcfg.sync.strategy != "flat" else None
+    # in the pod region `pod` is manual, so no constraint there may name it
+    over_pod = n_pods == 1
+    ac = (_act_constrain(mesh, over_pod=over_pod)
+          if tcfg.sync.strategy != "flat" else None)
+    lookups = {} if over_pod else _whole_vocab_lookups(mesh, ac)
     n_micro = max(1, tcfg.microbatches)
-    pod_sync = (
-        _make_pod_sync(mesh, tcfg, p_spec,
-                       with_residuals=res_abs is not None)
-        if n_pods > 1
-        else None
-    )
+
+    def loss(p, b):
+        return loss_fn(cfg, p, b, tcfg.compute_dtype, ac, **lookups)
+
+    def loss_and_grads(params, batch):
+        if n_micro == 1:
+            b = _constrain_batch(batch, mesh, over_pod=over_pod)
+            return jax.value_and_grad(lambda p: loss(p, b))(params)
+        # gradient accumulation: one fwd/bwd per microbatch; only the
+        # accumulated gradient crosses the pod boundary (per-step sync
+        # frequency unchanged — the paper's epoch semantics)
+        micro = jax.tree.map(
+            lambda x: x.reshape((n_micro, x.shape[0] // n_micro) + x.shape[1:]),
+            batch,
+        )
+        g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+
+        def mb_step(carry, mbatch):
+            gsum, lsum = carry
+            b = _constrain_batch(mbatch, mesh, over_pod=over_pod)
+            l, g = jax.value_and_grad(lambda p: loss(p, b))(params)
+            gsum = jax.tree.map(lambda a, x: a + x.astype(jnp.float32), gsum, g)
+            return (gsum, lsum + l), None
+
+        (gsum, lsum), _ = jax.lax.scan(
+            mb_step, (g0, jnp.zeros((), jnp.float32)), micro
+        )
+        grads = jax.tree.map(lambda g, p: (g / n_micro).astype(p.dtype), gsum, params)
+        return lsum / n_micro, grads
+
+    pod_step = (_make_pod_step(mesh, tcfg, p_spec, loss_and_grads)
+                if n_pods > 1 else None)
 
     def core(params, opt_state, residuals, batch):
         from ..dist import context as dist_context
 
         params = _constrain(params, p_shard)
         with dist_context.distribution(mesh):
-            if n_micro == 1:
-                b = _constrain_batch(batch, mesh)
-                loss, grads = jax.value_and_grad(
-                    lambda p: loss_fn(cfg, p, b, tcfg.compute_dtype, ac)
-                )(params)
+            if pod_step is None:
+                loss, grads = loss_and_grads(params, batch)
+                new_res = residuals
             else:
-                # gradient accumulation: one fwd/bwd per microbatch; only the
-                # accumulated gradient crosses the pod boundary (per-step sync
-                # frequency unchanged — the paper's epoch semantics)
-                micro = jax.tree.map(
-                    lambda x: x.reshape(
-                        (n_micro, x.shape[0] // n_micro) + x.shape[1:]
-                    ),
-                    batch,
-                )
-                g0 = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params
-                )
-
-                def mb_step(carry, mbatch):
-                    gsum, lsum = carry
-                    b = _constrain_batch(mbatch, mesh)
-                    l, g = jax.value_and_grad(
-                        lambda p: loss_fn(cfg, p, b, tcfg.compute_dtype, ac)
-                    )(params)
-                    gsum = jax.tree.map(
-                        lambda a, x: a + x.astype(jnp.float32), gsum, g
-                    )
-                    return (gsum, lsum + l), None
-
-                (gsum, lsum), _ = jax.lax.scan(
-                    mb_step, (g0, jnp.zeros((), jnp.float32)), micro
-                )
-                grads = jax.tree.map(
-                    lambda g, p: (g / n_micro).astype(p.dtype), gsum, params
-                )
-                loss = lsum / n_micro
-        new_res = residuals
-        if pod_sync is not None:
-            if res_abs is not None:
-                grads, new_res = pod_sync(grads, residuals)
-            else:
-                grads = pod_sync(grads)
+                loss, grads, new_res = pod_step(params, batch, residuals)
         new_params, new_opt, metrics = adamw_update(
             params, grads, opt_state, tcfg.optim
         )

@@ -37,7 +37,7 @@ from ..configs.base import ModelConfig
 from ..data.pipeline import DataConfig, SyntheticLM
 from ..models.model import init_params
 from ..optim.adamw import adamw_init
-from .train_step import TrainConfig, build_train_step
+from .train_step import TrainConfig, abstract_residuals, build_train_step
 
 __all__ = ["TrainerConfig", "Trainer", "StragglerMonitor"]
 
@@ -138,7 +138,7 @@ class Trainer:
         # one device and resharded afterwards
         sh = self.shardings
         init = jax.jit(
-            functools.partial(_init_state, model_cfg, tcfg),
+            functools.partial(_init_state, model_cfg, tcfg, mesh.shape.get("pod", 1)),
             out_shardings=(sh["params"], sh["opt"], sh["residuals"]),
         )
         self.params, self.opt_state, self.residuals = init(
@@ -291,15 +291,15 @@ class Trainer:
         return self.history
 
 
-def _init_state(model_cfg: ModelConfig, tcfg: TrainConfig, key):
-    """(params, optimizer state, residuals) of a fresh run."""
+def _init_state(model_cfg: ModelConfig, tcfg: TrainConfig, n_pods: int, key):
+    """(params, optimizer state, residuals) of a fresh run; the residuals
+    are zeros in the per-pod layout of ``abstract_residuals``."""
     params = jax.tree.map(
         lambda p: p.astype(tcfg.param_dtype), init_params(model_cfg, key)
     )
-    residuals = (
-        jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        if tcfg.sync.needs_residuals
-        else None
+    residuals = jax.tree.map(
+        lambda r: jnp.zeros(r.shape, r.dtype),
+        abstract_residuals(model_cfg, tcfg, n_pods),
     )
     return params, adamw_init(params, tcfg.optim), residuals
 

@@ -1,6 +1,6 @@
 """The named scopes of the train step (``attn``, ``moe_*``, ``embed``,
 ``logits``, ``adam``, ``pod_exchange``) change only the HLO metadata, and
-the pod exchange's collectives are the ones under ``pod_exchange``.
+the collectives over ``pod`` are the ones under ``pod_exchange``.
 8 forced host devices."""
 
 import contextlib
@@ -26,6 +26,7 @@ _METADATA = re.compile(r", metadata=\{[^}]*\}")
 _SOURCE_TABLES = re.compile(
     r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:\d+ .*\n)*", re.M)
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_SCALAR_RESULT = re.compile(r"=\s*\w+\[\]\S*\s")
 
 
 def tiny_step_hlo() -> str:
@@ -42,7 +43,7 @@ def tiny_step_hlo() -> str:
     batch = ts.input_specs(cfg, ShapeSpec("tiny", 64, 4, "train"))
     return make_jit(batch).lower(
         ts.abstract_params(cfg), ts.abstract_opt_state(cfg, tcfg),
-        ts.abstract_residuals(cfg, tcfg), batch).compile().as_text()
+        ts.abstract_residuals(cfg, tcfg, MESH_SHAPE["pod"]), batch).compile().as_text()
 
 
 def strip_metadata(text: str) -> str:
@@ -62,19 +63,17 @@ def test_scopes_change_only_metadata(scoped_hlo, monkeypatch):
 
 
 def test_the_pod_exchange_collectives_are_its_scope(scoped_hlo):
-    """Every collective over ``pod`` that the exchange region emits (its
-    op_name passes through the pod ``shard_map``) lies in ``pod_exchange``,
-    and each there spans ``pod`` alone.  The others over ``pod`` are the
-    backward pass's own reductions over the batch axes, which GSPMD places
-    before the region (``dist/collectives.py``, "Deployment note")."""
+    """Each pod takes its gradient from its own rows, so every collective
+    over ``pod`` larger than a scalar lies in ``pod_exchange`` and spans
+    ``pod`` alone; the loss mean is the one scalar that may lie outside."""
     in_scope = 0
     for line in scoped_hlo.splitlines():
         if not collectives_over(line, MESH_SHAPE, "pod"):
             continue
         path = _OP_NAME.search(line).group(1)
-        exchange = "/shard_map/" in path
-        assert exchange == ("/pod_exchange/" in path), line
-        if exchange:
+        assert classify_groups(line, MESH_SHAPE)[0] == {"pod"}, line
+        if "/pod_exchange/" in path:
             in_scope += 1
-            assert classify_groups(line, MESH_SHAPE)[0] == {"pod"}, line
+        else:
+            assert _SCALAR_RESULT.search(line), line
     assert in_scope

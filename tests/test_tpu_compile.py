@@ -16,7 +16,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs.registry import get_config
 from repro.dist.collectives import SyncConfig
@@ -27,7 +27,7 @@ from repro.kernels.rwkv6_wkv import ops as wkv
 from repro.kernels.whitedata_filter import ops as wd
 from repro.launch.hlo_cost import collectives_over
 from repro.launch.mesh import make_mesh
-from repro.train.train_step import TrainConfig, _make_pod_sync, abstract_params
+from repro.train.train_step import TrainConfig, _make_pod_step, abstract_params
 
 
 @pytest.fixture(scope="module")
@@ -99,19 +99,31 @@ def test_rglru_scan_compiles(one_chip):
 
 
 def test_pod_sync_compiles_with_pod_collectives(topo):
-    """The geococo exchange region (manual over `pod`, GSPMD over `data`)
-    over granite's real leaf shapes for one layer, on pod=2 x data=2."""
+    """The geococo exchange in the pod region (manual over `pod`, GSPMD over
+    `data`) over granite's real leaf shapes for one layer, on pod=2 x
+    data=2, with a stand-in for the pod's gradient: each pod scales the
+    parameters by its own row of the batch."""
     mesh = make_mesh((2, 2, 1), ("pod", "data", "model"), devices=topo.devices)
     cfg = dataclasses.replace(get_config("granite-moe-3b-a800m"), n_layers=1)
     tcfg = TrainConfig(sync=SyncConfig(strategy="geococo"))
     p_abs = abstract_params(cfg)
     specs = param_specs(p_abs, mesh, "geococo")
-    sync = _make_pod_sync(mesh, tcfg, specs, with_residuals=True)
-    tree = jax.tree.map(
+    step = _make_pod_step(
+        mesh, tcfg, specs,
+        lambda p, b: (b["w"][0], jax.tree.map(lambda x: x * b["w"][0], p)))
+    params = jax.tree.map(
         lambda l, s: jax.ShapeDtypeStruct(
             l.shape, jnp.float32, sharding=NamedSharding(mesh, s)
         ),
         p_abs, specs,
     )
-    pod = collectives_over(_hlo(sync, tree, tree), dict(mesh.shape), "pod")
+    res = jax.tree.map(
+        lambda l, s: jax.ShapeDtypeStruct(
+            (2,) + l.shape, jnp.float32, sharding=NamedSharding(mesh, P("pod", *s))
+        ),
+        p_abs, specs,
+    )
+    batch = {"w": jax.ShapeDtypeStruct((2,), jnp.float32,
+                                       sharding=NamedSharding(mesh, P("pod")))}
+    pod = collectives_over(_hlo(step, params, batch, res), dict(mesh.shape), "pod")
     assert "all-reduce" in pod
