@@ -29,11 +29,17 @@ pod's loss and gradient from that pod's own rows of the batch, inside a
 ``shard_map`` manual over ``pod``, so the gradients reaching
 ``sync_gradients`` differ by pod as they do between regions, and nothing
 else crosses the pod axis but the scalar loss mean.  ``geococo``'s
-error-feedback residuals therefore differ by pod too.  The exchange still
-moves dense bytes: :func:`chunked_topk_exchange` masks the unsent entries
-to zero and ``pmean``s the whole array, so the sparsification changes the
-update, and :func:`estimate_sync_bytes` gives the (value, index) bytes a
-sparse transport would move, not what the collective moves today.
+error-feedback residuals therefore differ by pod too.  Each chip
+exchanges its own FSDP/TP shard of a leaf wherever the shard is a whole
+number of the filter's ``chunk`` rows (:func:`shard_local_specs`): it
+filters and all-reduces over ``pod`` just those rows, which are the whole
+leaf's rows, so the result is the same to the bit.  Any other leaf (the
+unsharded ones, a misaligned one, a shard under ``min_leaf_size``) enters
+whole on every chip of the pod.  The exchange still moves dense bytes:
+:func:`chunked_topk_exchange` masks the unsent entries to zero and
+``pmean``s the whole array, so the sparsification changes the update, and
+:func:`estimate_sync_bytes` gives the (value, index) bytes a sparse
+transport would move, not what the collective moves today.
 """
 
 from __future__ import annotations
@@ -44,6 +50,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core import strategies
 
@@ -51,6 +58,8 @@ __all__ = [
     "SyncConfig",
     "DeviceSyncStrategy",
     "sync_gradients",
+    "shard_local_specs",
+    "exchange_local_share",
     "relay_psum",
     "chunked_topk_exchange",
     "estimate_sync_bytes",
@@ -286,7 +295,6 @@ def sync_gradients(
     *,
     axis: str = "pod",
     n_pods: int | None = None,
-    leaf_specs: Any = None,
 ) -> tuple[Any, Any]:
     """Synchronize a gradient pytree across pods under ``cfg.strategy``.
 
@@ -297,14 +305,16 @@ def sync_gradients(
     pod.  Every pod returns the same synced gradients (the mean over pods
     of what each sent) and its own new residuals.  The collectives move
     dense arrays (unsent entries as zeros).  With a single pod this is the
-    identity (the input objects are returned untouched).  ``leaf_specs`` is
-    accepted for callers that track per-leaf partitioning; the exchange
-    itself operates on whatever slice of each leaf the calling region holds.
+    identity (the input objects are returned untouched).
+
+    Each leaf is exchanged as the calling region holds it.  The train step
+    hands each chip its own shard of the leaves :func:`shard_local_specs`
+    marks, which it filters and all-reduces alone, and the whole of every
+    other leaf, which each chip of a pod filters alike.
 
     Returns ``(synced_grads, new_residuals)``.  ``new_residuals`` is ``None``
     whenever ``residuals`` is ``None`` and the strategy carries no state.
     """
-    del leaf_specs
     if n_pods is None or n_pods <= 1:
         return grads, residuals
     order = cfg.ring_order
@@ -336,6 +346,53 @@ def sync_gradients(
     synced = td.unflatten([o[0] for o in out])
     new_res = td.unflatten([o[1] for o in out])
     return synced, new_res
+
+
+def _ways(part, mesh_shape) -> int:
+    """How many shards one entry of a ``PartitionSpec`` cuts its dim into."""
+    names = () if part is None else part if isinstance(part, tuple) else (part,)
+    return math.prod(mesh_shape[a] for a in names)
+
+
+def shard_local_specs(leaves: Any, specs: Any, mesh_shape, cfg: SyncConfig) -> Any:
+    """The in-pod ``PartitionSpec`` with which each leaf of a gradient
+    enters the exchange: its own spec where each chip may filter its shard
+    alone, ``P()`` (the whole leaf on every chip) elsewhere.
+
+    The filter cuts a leaf's row-major flatten into rows of ``cfg.chunk``.
+    In that flatten a shard is a series of contiguous runs of
+    ``shape[d] // s * prod(shape[d+1:])`` elements, ``d`` the innermost dim
+    the spec splits ``s`` ways, each run starting at a multiple of its
+    length.  Where the run is a multiple of ``chunk``, the shard's own
+    flatten cut into rows is a subset of the whole leaf's rows, in order;
+    top-k is per row, so what is sent, the pod mean and the residual are the
+    same to the bit.  The shard must also hold ``cfg.min_leaf_size``
+    elements, so that ``sync_gradients``, which chooses between the dense
+    mean and the filter on the size it sees, chooses as for the whole leaf.
+    """
+
+    def one(leaf, spec):
+        ways = [_ways(part, mesh_shape) for part in spec]
+        shards = math.prod(ways)
+        if shards == 1:
+            return P()
+        d = max(i for i, w in enumerate(ways) if w > 1)
+        run = leaf.shape[d] // ways[d] * math.prod(leaf.shape[d + 1:])
+        if run % cfg.chunk or leaf.size // shards < cfg.min_leaf_size:
+            return P()
+        return spec
+
+    return jax.tree.map(one, leaves, specs)
+
+
+def exchange_local_share(leaves: Any, plan: Any) -> float:
+    """The share of the elements of ``leaves`` that :func:`shard_local_specs`'s
+    ``plan`` exchanges on their shard (0 for no plan)."""
+    if plan is None:
+        return 0.0
+    pairs = list(zip(jax.tree.leaves(leaves), jax.tree.leaves(plan)))
+    local = sum(leaf.size for leaf, spec in pairs if spec != P())
+    return local / sum(leaf.size for leaf, _ in pairs)
 
 
 # ---------------------------------------------------------------------------

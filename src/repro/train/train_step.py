@@ -9,7 +9,8 @@ is manual over ``pod`` only (the ``data`` / ``model`` axes stay with GSPMD
 inside it): each pod takes the loss and gradient of its own share of the
 batch, as a region computes on its own sequences, and
 ``repro.dist.collectives.sync_gradients`` then exchanges them under the
-configured strategy, resolved through the two-plane registry.  That
+configured strategy, resolved through the two-plane registry, each chip
+on its own shard of a leaf wherever that gives the whole leaf's rows.  That
 exchange and the scalar loss mean are the only traffic over ``pod``.  This
 split — GSPMD inside the pod, an explicit collective program across pods —
 mirrors the paper's architecture (intra-group transfers are cheap and
@@ -32,7 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig, ShapeSpec
-from ..dist.collectives import SyncConfig, sync_gradients
+from ..dist.collectives import SyncConfig, shard_local_specs, sync_gradients
 from ..dist.sharding import param_shardings, param_specs
 from ..models.model import forward, init_cache, init_params
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
@@ -310,7 +311,7 @@ def _whole_vocab_lookups(mesh: Mesh, ac) -> dict:
     return {"embed_fn": embed, "logp_constrain": ac}
 
 
-def _make_pod_step(mesh: Mesh, tcfg: TrainConfig, p_spec, loss_and_grads):
+def _make_pod_step(mesh: Mesh, tcfg: TrainConfig, p_spec, plan, loss_and_grads):
     """Each pod's loss and gradient, then the exchange across pods.
 
     ``loss_and_grads(params, batch)`` runs inside a ``shard_map`` manual
@@ -318,24 +319,38 @@ def _make_pod_step(mesh: Mesh, tcfg: TrainConfig, p_spec, loss_and_grads):
     at their in-pod partitioning (only the pod components of ``p_spec``
     survive; GSPMD keeps FSDP/TP), so nothing crosses ``pod`` before
     ``sync_gradients`` exchanges the gradients under the configured
-    strategy, in the ``pod_exchange`` scope.  Residuals carry a leading pod
-    axis, one slice per pod.  Returns ``step(params, batch, residuals) ->
-    (loss mean over pods, synced grads, new residuals)``.
+    strategy, in the ``pod_exchange`` scope.  The exchange runs in a
+    ``shard_map`` nested manual over the in-pod axes, on each leaf as
+    ``plan`` (``shard_local_specs``) gives it: the chip's own shard, or the
+    whole leaf.  Residuals carry a leading pod axis, one slice per pod.
+    Returns ``step(params, batch, residuals) -> (loss mean over pods,
+    synced grads, new residuals)``.
     """
     n_pods = mesh.shape["pod"]
+    g_shard = jax.tree.map(lambda s: NamedSharding(mesh, s), p_spec)
     p_spec = jax.tree.map(_strip_auto_axes, p_spec)
+    in_pod = {a for a in mesh.axis_names if a != "pod"}
+
+    def exchange(grads, res):
+        return sync_gradients(grads, res, tcfg.sync, axis="pod", n_pods=n_pods)
 
     def body(params, batch, residuals):
         loss, grads = loss_and_grads(params, batch)
         # the whole backward pass before any of the exchange: left free, the
         # TPU scheduler overlaps the exchange's temporaries with it, and the
-        # 8-layer granite step on pod=2 x data=2 needs 15.81 GiB of a v5e's
-        # 15.75 (15.02 GiB with the barrier)
-        grads = jax.lax.optimization_barrier(grads)
+        # 8-layer granite step on pod=2 x data=2 with whole-leaf exchanges
+        # needed 15.81 GiB of a v5e's 15.75 (15.02 GiB with the barrier).
+        # The gradients leave it at their parameters' layout, whatever
+        # layout the exchange takes them in, so the plan cannot change how
+        # GSPMD sums them (a tied embedding's two parts, say)
+        grads = jax.lax.optimization_barrier(_constrain(grads, g_shard))
         res = None if residuals is None else jax.tree.map(lambda r: r[0], residuals)
+        specs = (plan, None if res is None else plan)
         with jax.named_scope("pod_exchange"):
-            grads, res = sync_gradients(grads, res, tcfg.sync, axis="pod",
-                                        n_pods=n_pods)
+            grads, res = jax.shard_map(
+                exchange, mesh=jax.sharding.get_abstract_mesh(), in_specs=specs,
+                out_specs=specs, axis_names=in_pod, check_vma=False,
+            )(grads, res)
         if res is not None:
             res = jax.tree.map(lambda r: r[None], res)
         return jax.lax.pmean(loss, "pod"), grads, res
@@ -360,7 +375,9 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
 
     With more than one pod each pod's gradient comes from its own rows of
     the batch and crosses ``pod`` only through the exchange
-    (:func:`_make_pod_step`); the loss reported is the mean over pods.
+    (:func:`_make_pod_step`); the loss reported is the mean over pods.  The
+    dict also holds ``"exchange"``: the in-pod spec each gradient leaf
+    enters the exchange with (``shard_local_specs``), ``None`` at one pod.
     """
     n_pods = mesh.shape.get("pod", 1)
     p_abs = abstract_params(cfg, tcfg.param_dtype)
@@ -412,8 +429,10 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
         grads = jax.tree.map(lambda g, p: (g / n_micro).astype(p.dtype), gsum, params)
         return lsum / n_micro, grads
 
-    pod_step = (_make_pod_step(mesh, tcfg, p_spec, loss_and_grads)
-                if n_pods > 1 else None)
+    plan = pod_step = None
+    if n_pods > 1:
+        plan = shard_local_specs(p_abs, p_spec, mesh.shape, tcfg.sync)
+        pod_step = _make_pod_step(mesh, tcfg, p_spec, plan, loss_and_grads)
 
     def core(params, opt_state, residuals, batch):
         from ..dist import context as dist_context
@@ -443,7 +462,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
             donate_argnums=(0, 1, 2),
         )
 
-    shardings = {"params": p_shard, "opt": opt_shard, "residuals": res_shard}
+    shardings = {"params": p_shard, "opt": opt_shard, "residuals": res_shard,
+                 "exchange": plan}
     return make_jit, shardings
 
 

@@ -35,6 +35,7 @@ from .. import checkpoint as _ckpt_pkg  # noqa: F401  (namespace)
 from ..checkpoint.checkpoint import latest_step, restore, save, save_async
 from ..configs.base import ModelConfig
 from ..data.pipeline import DataConfig, SyntheticLM
+from ..dist.collectives import exchange_local_share
 from ..models.model import init_params
 from ..optim.adamw import adamw_init
 from .train_step import TrainConfig, abstract_residuals, build_train_step
@@ -144,6 +145,13 @@ class Trainer:
         self.params, self.opt_state, self.residuals = init(
             jax.random.PRNGKey(run_cfg.seed)
         )
+        # share of the gradient's elements the pod exchange takes on each
+        # chip's own shard (0 with one pod)
+        self.exchange_local_share = exchange_local_share(self.params,
+                                                         sh["exchange"])
+        if sh["exchange"] is not None:
+            print(f"pod exchange: {self.exchange_local_share:.4f} of the "
+                  "gradient's elements on each chip's own shard")
         self.step_idx = 0
         self._step_fn = None
 

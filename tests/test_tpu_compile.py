@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs.registry import get_config
-from repro.dist.collectives import SyncConfig
+from repro.dist.collectives import SyncConfig, shard_local_specs
 from repro.dist.sharding import param_specs
 from repro.kernels.crdt_merge import ops as crdt
 from repro.kernels.rglru_scan import ops as rglru
@@ -102,14 +102,15 @@ def test_pod_sync_compiles_with_pod_collectives(topo):
     """The geococo exchange in the pod region (manual over `pod`, GSPMD over
     `data`) over granite's real leaf shapes for one layer, on pod=2 x
     data=2, with a stand-in for the pod's gradient: each pod scales the
-    parameters by its own row of the batch."""
+    parameters by its own row of the batch.  Each chip reduces its own half
+    of an expert leaf over ``pod`` (20 of 40 experts: 7,680 rows of 2048)."""
     mesh = make_mesh((2, 2, 1), ("pod", "data", "model"), devices=topo.devices)
     cfg = dataclasses.replace(get_config("granite-moe-3b-a800m"), n_layers=1)
     tcfg = TrainConfig(sync=SyncConfig(strategy="geococo"))
     p_abs = abstract_params(cfg)
     specs = param_specs(p_abs, mesh, "geococo")
     step = _make_pod_step(
-        mesh, tcfg, specs,
+        mesh, tcfg, specs, shard_local_specs(p_abs, specs, dict(mesh.shape), tcfg.sync),
         lambda p, b: (b["w"][0], jax.tree.map(lambda x: x * b["w"][0], p)))
     params = jax.tree.map(
         lambda l, s: jax.ShapeDtypeStruct(
@@ -125,5 +126,8 @@ def test_pod_sync_compiles_with_pod_collectives(topo):
     )
     batch = {"w": jax.ShapeDtypeStruct((2,), jnp.float32,
                                        sharding=NamedSharding(mesh, P("pod")))}
-    pod = collectives_over(_hlo(step, params, batch, res), dict(mesh.shape), "pod")
+    text = _hlo(step, params, batch, res)
+    pod = collectives_over(text, dict(mesh.shape), "pod")
     assert "all-reduce" in pod
+    assert any("f32[7680,2048]" in line for line in text.splitlines()
+               if collectives_over(line, dict(mesh.shape), "pod"))
