@@ -1,7 +1,7 @@
 """Long-horizon streaming: O(E) incremental timeline + vectorized OCC.
 
 Before this module's tentpole, every epoch of a streaming feedback run
-re-stitched and re-simulated the entire prefix (``_stream_prefix``), making
+re-stitched and re-simulated the entire prefix (now the ``resim`` oracle), making
 an E-epoch run O(E^2) in simulated transfers — 1000-epoch traces were
 unreachable.  The :class:`repro.core.stream.StreamingTimeline` keeps the
 event-engine state (NIC clear floors + frontier finish times) alive across

@@ -96,7 +96,7 @@ CRITICAL_FUNCS = {
     "digest", "value_state", "full_state", "merge_updates", "apply_many",
     "merge_store", "validate_epoch", "validate_epoch_detailed",
     "_validate_python", "_validate_numpy",
-    "committed_updates", "_advance_views", "advance_views", "append_epoch",
+    "committed_updates", "advance_views", "commit_at", "append_epoch",
 }
 
 # Allowlists: entries are a path suffix (posix), optionally "::"-scoped to a

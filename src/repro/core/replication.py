@@ -59,9 +59,9 @@ with GeoCoCo optionally relaying through group aggregators.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
-import warnings
 import zlib as _zlib
 from typing import Callable, Sequence
 
@@ -70,7 +70,7 @@ import numpy as np
 from . import strategies as _strategies
 from .crdt import DeltaCRDTStore, Update
 from .occ import Txn, txn_updates, validate_epoch_detailed
-from .planner import GroupPlan, Replanner, no_grouping
+from .planner import GroupPlan, no_grouping
 from .schedule import (
     TransmissionSchedule,
     all_to_all_schedule,
@@ -479,6 +479,104 @@ def advance_views(
         del pending_ups[k]
 
 
+class _Timing:
+    """Where an epoch's commit times come from; :meth:`GeoCluster.run` asks
+    this once per run.  ``append(rnd, lat)`` takes a simulated round and
+    returns the marks of the epochs whose commit times are now final, in
+    epoch order; ``finish()`` returns the rest.  A mark is ``None`` (no
+    stream: the formula times the epoch) or ``(absolute commit ms,
+    cumulative per-node commit row)``.  ``commit_at(k, i)`` and ``n_done``
+    feed the feedback loop's :func:`advance_views`; ``evict(before)``
+    releases commit rows below the views' merge frontier.
+
+    This base is the isolated source of the barrier and event engines: no
+    cross-epoch stream, so an epoch is final as soon as its round is."""
+
+    n_done = 0
+
+    def append(self, rnd: _EpochRound, lat: np.ndarray) -> list:
+        return [None]
+
+    def finish(self) -> list:
+        return []
+
+    def evict(self, before: int) -> None:
+        pass
+
+
+class _TimelineTiming(_Timing):
+    """``stream_mode="incremental"``: each round is appended onto a
+    :class:`~repro.core.stream.StreamingTimeline`, which simulates only its
+    events.  With bandwidth admission later arrivals never move an earlier
+    epoch, so its times are final the moment the append returns: O(E) time,
+    and memory bounded by the slowest view (:meth:`evict`)."""
+
+    def __init__(self, cluster: "GeoCluster"):
+        cfg = cluster.cfg
+        self._timeline = StreamingTimeline(
+            cfg.n_nodes, bandwidth_mbps=cluster.bandwidth, loss=cluster.loss,
+            epoch_ms=cfg.epoch_ms, verify=cfg.verify_schedules,
+        )
+        self.commit_at = self._timeline.commit_at
+
+    def append(self, rnd: _EpochRound, lat: np.ndarray) -> list:
+        et = self._timeline.append_epoch(rnd.schedule, lat,
+                                         node_exec_ms=rnd.node_exec_ms)
+        self.n_done = self._timeline.n_epochs
+        return [(et.finish_max_ms, et.commit_ms)]
+
+    def evict(self, before: int) -> None:
+        self._timeline.evict_commit_rows(before)
+
+
+class _ResimTiming(_Timing):
+    """``stream_mode="resim"``, the O(E²) reference oracle: it keeps every
+    round, and stitches and re-simulates the whole prefix — after each
+    append under ``staleness_feedback`` (the views need each epoch's
+    times), once at :meth:`finish` otherwise."""
+
+    def __init__(self, cluster: "GeoCluster", lats: EpochLatencyCycle):
+        self._cluster = cluster
+        self._lats = lats
+        self._feedback = cluster.cfg.staleness_feedback
+        self._schedules: list[TransmissionSchedule] = []
+        self._exec: list[np.ndarray] = []
+        self._commit = np.zeros((0, cluster.cfg.n_nodes))
+
+    def commit_at(self, k: int, i: int) -> float:
+        return float(self._commit[k, i])
+
+    def append(self, rnd: _EpochRound, lat: np.ndarray) -> list:
+        self._schedules.append(rnd.schedule)
+        self._exec.append(rnd.node_exec_ms)
+        if not self._feedback:
+            return []
+        return self._stream()[-1:]
+
+    def finish(self) -> list:
+        if self._feedback or not self._schedules:
+            return []
+        return self._stream()
+
+    def _stream(self) -> list:
+        """Stitch every round so far, run one event simulation over them
+        and return every epoch's mark."""
+        c, cfg = self._cluster, self._cluster.cfg
+        stitched = stitch_schedules(self._schedules, node_exec_ms=self._exec,
+                                    epoch_ms=cfg.epoch_ms, n=cfg.n_nodes)
+        sim = WANSimulator(self._lats[0], c.bandwidth, loss=c.loss,
+                           rng=c.rng, verify=cfg.verify_schedules)
+        stream = sim.run(stitched, lats=self._lats)
+        n_epochs = len(self._schedules)
+        self._commit = node_commit_ms(stitched, stream, cfg.n_nodes, n_epochs)
+        self.n_done = n_epochs
+        # per-epoch absolute commit marks in one grouped pass
+        epoch_of = np.array([t.epoch for t in stitched.transfers])
+        marks = np.full(n_epochs, -np.inf)
+        np.maximum.at(marks, epoch_of, stream.finish_ms)
+        return list(zip(marks.tolist(), self._commit))
+
+
 class GeoCluster:
     """Full-replica multi-master cluster over a simulated WAN."""
 
@@ -576,18 +674,6 @@ class GeoCluster:
         )
         self.plan_time_s += time.perf_counter() - t0
         return plan
-
-    @property
-    def _replanner(self) -> Replanner:
-        """Deprecated: the engine no longer owns a private Replanner."""
-        warnings.warn(
-            "GeoCluster._replanner is deprecated; use GeoCluster.control "
-            "(a repro.control.ControlPlane) — e.g. control.plan, "
-            "control.replan_count, control.on_node_failure()",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        return self.control.replanner
 
     # -- one epoch -------------------------------------------------------------
 
@@ -787,14 +873,16 @@ class GeoCluster:
         rnd: "_EpochRound",
         sim: WANSimulator,
         res,
-        *,
-        wall_ms: float | None = None,
-        pipeline_overlap_ms: float = 0.0,
-        stream_commit_ms: float = 0.0,
-        view_lag_mean: float = 0.0,
-        view_lag_max: int = 0,
+        mark: tuple[float, np.ndarray] | None = None,
+        prev_commit_ms: float = 0.0,
+        lag: tuple[float, int] = (0.0, 0),
     ) -> EpochStats:
-        """Assemble one epoch's stats from its (isolated) round simulation."""
+        """Assemble one epoch's stats from its (isolated) round simulation.
+        ``mark`` is the timing source's (absolute commit, commit row) of the
+        epoch in the cross-epoch stream; without a stream (None) the
+        wall-clock is the formula ``max(epoch_ms, exec_ms, sync_ms)``.
+        ``lag`` is the views' (mean, max) lag in epochs when the epoch
+        executed."""
         cfg = self.cfg
         schedule = rnd.schedule
         if cfg.barrier:
@@ -833,8 +921,9 @@ class GeoCluster:
             wan_bytes = float((res.link_bytes * self.wan_mask).sum())
         else:
             wan_bytes = res.total_bytes
-        if wall_ms is None:
-            wall_ms = max(cfg.epoch_ms, rnd.exec_ms, res.makespan_ms)
+        formula_ms = max(cfg.epoch_ms, rnd.exec_ms, res.makespan_ms)
+        commit_ms = 0.0 if mark is None else mark[0]
+        wall_ms = formula_ms if mark is None else commit_ms - prev_commit_ms
         return EpochStats(
             epoch=rnd.epoch,
             n_txns=rnd.n_txns,
@@ -851,55 +940,126 @@ class GeoCluster:
             sync_overlap_ms=sync_overlap_ms,
             sync_cpu_hidden_ms=cpu_hidden_ms,
             sync_wan_overlap_ms=wan_overlap_ms,
-            pipeline_overlap_ms=pipeline_overlap_ms,
-            stream_commit_ms=stream_commit_ms,
+            pipeline_overlap_ms=formula_ms - wall_ms,
+            stream_commit_ms=commit_ms,
             read_aborts=rnd.read_aborts,
             ww_aborts=rnd.ww_aborts,
-            view_lag_mean=view_lag_mean,
-            view_lag_max=view_lag_max,
+            view_lag_mean=lag[0],
+            view_lag_max=lag[1],
         )
 
-    def run_epoch(
-        self,
-        epoch: int,
-        txns_by_node: dict[int, list[Txn]],
-        lat: np.ndarray,
-    ) -> EpochStats:
-        cfg = self.cfg
-        rnd = self._prepare_epoch(epoch, txns_by_node, lat)
-        sim = WANSimulator(lat, self.bandwidth, loss=self.loss, rng=self.rng,
-                           barrier=cfg.barrier, verify=cfg.verify_schedules)
-        res = sim.run(rnd.schedule)
-        self.msg_matrix += res.msg_matrix
-        return self._epoch_stats(rnd, sim, res)
+    # -- the epoch loop --------------------------------------------------------
 
-    # -- full run ----------------------------------------------------------------
+    def _timing_source(self, lats: EpochLatencyCycle) -> _Timing:
+        """The one place the engine's commit timing is chosen."""
+        if not self.cfg.streaming:
+            return _Timing()
+        if self.cfg.stream_mode == "incremental":
+            return _TimelineTiming(self)
+        return _ResimTiming(self, lats)
 
-    def run(
-        self,
-        generator,
-        trace,
-        *,
-        txns_per_node: int = 20,
-        n_epochs: int | None = None,
-    ) -> RunStats:
+    def run(self, generator, trace, *, txns_per_node: int = 20,
+            n_epochs: int | None = None) -> RunStats:
+        """Run ``n_epochs`` epochs (default ``len(trace)``); epoch ``e`` sees
+        ``trace[e % len(trace)]``.
+
+        One loop serves every engine.  Each epoch it advances the per-node
+        snapshot views (``staleness_feedback`` only), draws the epoch's
+        transactions against them (else against the replicated store),
+        prepares the timing-independent round (:meth:`_prepare_epoch`),
+        simulates it in isolation (the reference for ``sync_ms``, the
+        serial/overlap split and the byte accounting) and hands it to the
+        timing source.  Epochs whose commit times are then final go, in
+        epoch order, to the sinks: the run aggregator and, with ``serve``,
+        the serving plane's :class:`~repro.serve.plane.ServingSink`.
+
+        The timing source (:meth:`_timing_source`) is the only difference
+        between the engines: none for the barrier and event engines (the
+        wall-clock is ``max(epoch_ms, exec_ms, sync_ms)``), an appendable
+        :class:`~repro.core.stream.StreamingTimeline` for
+        ``stream_mode="incremental"``, and the O(E²) re-simulation oracle
+        for ``"resim"``; the two streams' times are byte-identical.  Commit
+        content never depends on the timing, so without feedback all four
+        engines' digests agree.
+
+        With ``staleness_feedback=True`` the transactions of epoch ``e``
+        execute optimistically at ``e * epoch_ms`` against the executing
+        node's view, which holds only the epochs the stream has delivered
+        to that node by then, so read-validation aborts become a function
+        of network conditions.  (Sends stay gated on the node's
+        previous-epoch commit, as in the stitched DAG.)
+        """
         cfg = self.cfg
+        n = cfg.n_nodes
+        feedback = cfg.staleness_feedback
         n_epochs = n_epochs if n_epochs is not None else len(trace)
-        # every run path pushes its finalized EpochStats through the
-        # aggregator sink the moment the epoch's numbers are final; the
-        # retained list and the online summary both come from it
+        lats = EpochLatencyCycle(trace, max(n_epochs, 1))
+        timing = self._timing_source(lats)
         agg = RunAggregator(keep_epochs=cfg.keep_epochs,
                             window=cfg.stats_window)
+        sinks: list[EpochSink] = [agg]
+        serve_sink = None
+        if cfg.serve is not None:
+            from ..serve.plane import ServingSink
+
+            serve_sink = ServingSink(cfg.serve, n, cfg.epoch_ms)
+            sinks.append(serve_sink)
+        views = view_next = None
+        pending_ups: dict[int, list[Update]] = {}  # epoch -> unmerged updates
+        if feedback:
+            views = [DeltaCRDTStore(i) for i in range(n)]
+            view_next = np.zeros(n, dtype=int)
+        # simulated rounds whose commit times are not final yet
+        pending: collections.deque = collections.deque()
+        last_commit = 0.0
+
+        def release(marks) -> None:
+            nonlocal last_commit
+            for mark in marks:
+                rnd, sim, res, lag = pending.popleft()
+                stats = self._epoch_stats(rnd, sim, res, mark, last_commit, lag)
+                ctx = None
+                if mark is not None:
+                    last_commit, row = mark
+                    ctx = EpochContext(epoch=rnd.epoch, commit_row=row,
+                                       lat=lats[rnd.epoch])
+                for s in sinks:
+                    s.on_epoch(stats, ctx)
+
+        for e in range(n_epochs):
+            lat = lats[e]
+            lag = (0.0, 0)
+            snapshot = self.store
+            if feedback:
+                advance_views(n, views, view_next, pending_ups,
+                              timing.commit_at, timing.n_done,
+                              e * cfg.epoch_ms)
+                behind = e - view_next
+                lag = (float(behind.mean()), int(behind.max()))
+                snapshot = views
+            txns = generator.epoch_txns(e, txns_per_node, snapshot=snapshot)
+            rnd = self._prepare_epoch(e, txns, lat, views=views)
+            sim = WANSimulator(lat, self.bandwidth, loss=self.loss,
+                               rng=self.rng, barrier=cfg.barrier,
+                               verify=cfg.verify_schedules)
+            res = sim.run(rnd.schedule)
+            self.msg_matrix += res.msg_matrix
+            pending.append((rnd, sim, res, lag))
+            release(timing.append(rnd, lat))
+            if feedback:
+                pending_ups[e] = rnd.ups
+            # commit rows below the slowest view's merge frontier are never
+            # read again (advance_views only reads forward of view_next);
+            # without feedback nothing reads them after the sinks have
+            timing.evict(int(view_next.min()) if feedback else e + 1)
+        release(timing.finish())
         serve_stats = None
-        if cfg.streaming:
-            serve_stats = self._run_streaming(
-                generator, trace, txns_per_node, n_epochs, agg
+        if serve_sink is not None and n_epochs:
+            # wall_ms covers the full client window even when the last
+            # commit lands inside it
+            serve_stats = serve_sink.finish(
+                wall_ms=max(last_commit, n_epochs * cfg.epoch_ms)
             )
-        else:
-            for e in range(n_epochs):
-                lat = trace[e % len(trace)]
-                txns = generator.epoch_txns(e, txns_per_node, snapshot=self.store)
-                agg.on_epoch(self.run_epoch(e, txns, lat))
         return RunStats(
             epochs=agg.epochs,
             msg_matrix=self.msg_matrix.copy(),
@@ -909,286 +1069,6 @@ class GeoCluster:
             serve=serve_stats,
             summary=agg.summary,
         )
-
-    def _stream_prefix(self, rounds: list["_EpochRound"], lats):
-        """Stitch the epochs prepared so far and run the streaming event
-        simulation over them.  ``lats`` indexes each epoch's latency matrix
-        (an :class:`~repro.core.simulator.EpochLatencyCycle`).  Returns
-        (per-node commit-time matrix, stream RoundResult, stitched schedule).
-
-        This is the O(E²) reference oracle (``stream_mode="resim"``): with
-        feedback it re-simulates the whole prefix every epoch.  The default
-        ``stream_mode="incremental"`` appends onto a
-        :class:`~repro.core.stream.StreamingTimeline` instead, with
-        byte-identical timings (tested against this method)."""
-        cfg = self.cfg
-        stitched = stitch_schedules(
-            [r.schedule for r in rounds],
-            node_exec_ms=[r.node_exec_ms for r in rounds],
-            epoch_ms=cfg.epoch_ms,
-            n=cfg.n_nodes,
-        )
-        stream_sim = WANSimulator(lats[0], self.bandwidth,
-                                  loss=self.loss, rng=self.rng,
-                                  verify=cfg.verify_schedules)
-        stream = stream_sim.run(stitched, lats=lats)
-        commits = node_commit_ms(stitched, stream, cfg.n_nodes, len(rounds))
-        return commits, stream, stitched
-
-    def _advance_views(
-        self,
-        views: list[DeltaCRDTStore],
-        view_next: np.ndarray,
-        pending_ups: dict[int, list[Update]],
-        commit_at: Callable[[int, int], float],
-        n_done: int,
-        now_ms: float,
-    ) -> None:
-        advance_views(self.cfg.n_nodes, views, view_next, pending_ups,
-                      commit_at, n_done, now_ms)
-
-    def _run_streaming(
-        self, generator, trace, txns_per_node: int, n_epochs: int,
-        agg: RunAggregator,
-    ) -> ServeStats | None:
-        """Cross-epoch streaming: stitch every epoch's DAG and measure real
-        per-epoch commit times from one event-driven simulation.
-
-        The per-epoch loop still runs each round in isolation — that
-        simulation is the reference the stats are split against (sync_ms,
-        the serial/overlap split, byte accounting) and what
-        ``pipeline_overlap_ms`` compares the measured wall-clock to.
-        Commits are processed inside the loop exactly as in the
-        non-streaming engine, so with ``staleness_feedback=False`` the
-        final digests are byte-identical.
-
-        With ``staleness_feedback=True`` the loop closes the timing -> OCC
-        feedback: transactions of epoch ``e`` execute optimistically when
-        they *arrive* (``e * epoch_ms`` — GeoGauss executes at cadence, it
-        does not stall the CPU on remote state) against the executing
-        node's snapshot view, which advances only as the stitched
-        simulation delivers that node's inbound epoch transfers.  A node
-        paying off a WAN backlog therefore versions its reads against an
-        epoch ``e-k`` snapshot, and the read-validation rule aborts exactly
-        the transactions whose reads the backlog made stale — abort rate
-        becomes a function of network conditions.  (Write-set *sends*
-        remain gated on the node's previous-epoch commit, as in the
-        stitched timing DAG: execution is optimistic, transmission stays
-        ordered.)
-
-        The stream is timed incrementally by default
-        (``stream_mode="incremental"``): each epoch appends onto a
-        :class:`~repro.core.stream.StreamingTimeline` that simulates only
-        the new events — with bandwidth admission an earlier epoch's
-        measured times are unaffected by later arrivals, so the prefix
-        times are final and the incremental timings are byte-identical to
-        re-simulating the whole prefix (``stream_mode="resim"``, the O(E²)
-        reference oracle).  That same finality is what makes the
-        incremental path a *bounded-memory pipeline*: each epoch's
-        ``EpochStats`` is assembled eagerly and pushed through the attached
-        :class:`~repro.core.sinks.EpochSink`\\ s (the run aggregator, the
-        serving plane's :class:`~repro.serve.plane.ServingSink`), per-round
-        simulators and results are dropped on the spot, committed updates
-        are retained only until the slowest view merges past them
-        (``view_next.min()``), and the timeline's commit window is evicted
-        at the same frontier.  The resim oracle necessarily retains the
-        full prefix (it re-simulates it) and keeps the historical batch
-        shape.
-        """
-        if self.cfg.stream_mode == "incremental":
-            return self._run_streaming_incremental(
-                generator, trace, txns_per_node, n_epochs, agg
-            )
-        return self._run_streaming_resim(
-            generator, trace, txns_per_node, n_epochs, agg
-        )
-
-    def _run_streaming_incremental(
-        self, generator, trace, txns_per_node: int, n_epochs: int,
-        agg: RunAggregator,
-    ) -> ServeStats | None:
-        """The O(E)-time, frontier-bounded-memory streaming path (see
-        :meth:`_run_streaming`)."""
-        cfg = self.cfg
-        feedback = cfg.staleness_feedback
-        lat_cycle = EpochLatencyCycle(trace, max(n_epochs, 1))
-        timeline = StreamingTimeline(
-            cfg.n_nodes, bandwidth_mbps=self.bandwidth, loss=self.loss,
-            epoch_ms=cfg.epoch_ms, verify=cfg.verify_schedules,
-        )
-        serve_sink = None
-        sinks: list[EpochSink] = [agg]
-        if cfg.serve is not None:
-            from ..serve.plane import ServingSink
-
-            serve_sink = ServingSink(cfg.serve, cfg.n_nodes, cfg.epoch_ms)
-            sinks.append(serve_sink)
-        views = view_next = None
-        # committed updates awaiting view merges, epoch -> updates; entries
-        # are released once every view's frontier passes them
-        pending_ups: dict[int, list[Update]] = {}
-        if feedback:
-            views = [DeltaCRDTStore(i) for i in range(cfg.n_nodes)]
-            view_next = np.zeros(cfg.n_nodes, dtype=int)
-        prev_commit = 0.0
-        for e in range(n_epochs):
-            lat = lat_cycle[e]
-            if feedback:
-                self._advance_views(views, view_next, pending_ups,
-                                    timeline.commit_at, timeline.n_epochs,
-                                    e * cfg.epoch_ms)
-                lag = e - view_next
-                lag_mean = float(lag.mean()) if lag.size else 0.0
-                lag_max = int(lag.max()) if lag.size else 0
-                snapshot = views
-            else:
-                lag_mean, lag_max = 0.0, 0
-                snapshot = self.store
-            txns = generator.epoch_txns(e, txns_per_node, snapshot=snapshot)
-            rnd = self._prepare_epoch(e, txns, lat, views=views)
-            sim = WANSimulator(lat, self.bandwidth, loss=self.loss,
-                               rng=self.rng, verify=cfg.verify_schedules)
-            res = sim.run(rnd.schedule)
-            self.msg_matrix += res.msg_matrix
-            # O(this epoch's events): the timeline carries the stream
-            # frontier; by the admission theorem this epoch's times are
-            # final the moment the append returns, so the stats can be
-            # extracted and pushed downstream immediately
-            et = timeline.append_epoch(rnd.schedule, lat,
-                                       node_exec_ms=rnd.node_exec_ms)
-            commit = et.finish_max_ms
-            wall = commit - prev_commit
-            prev_commit = commit
-            formula = max(cfg.epoch_ms, rnd.exec_ms, res.makespan_ms)
-            stats = self._epoch_stats(
-                rnd, sim, res,
-                wall_ms=wall,
-                pipeline_overlap_ms=formula - wall,
-                stream_commit_ms=commit,
-                view_lag_mean=lag_mean,
-                view_lag_max=lag_max,
-            )
-            ctx = EpochContext(epoch=e, commit_row=et.commit_ms, lat=lat)
-            for s in sinks:
-                s.on_epoch(stats, ctx)
-            if feedback:
-                pending_ups[e] = rnd.ups
-                # commit rows below the slowest view's merge frontier can
-                # never be read again (_advance_views only reads forward of
-                # view_next); drop them from the timeline's window
-                timeline.evict_commit_rows(int(view_next.min()))
-            else:
-                # no feedback loop: nothing ever reads the commit window
-                # (the serving sink already consumed this epoch's row)
-                timeline.evict_commit_rows(timeline.n_epochs)
-        if serve_sink is None or n_epochs == 0:
-            return None
-        # wall_ms covers the full client window even when the last commit
-        # lands inside it
-        return serve_sink.finish(
-            wall_ms=max(prev_commit, n_epochs * cfg.epoch_ms)
-        )
-
-    def _run_streaming_resim(
-        self, generator, trace, txns_per_node: int, n_epochs: int,
-        agg: RunAggregator,
-    ) -> ServeStats | None:
-        """The O(E²) re-simulation oracle (see :meth:`_run_streaming`) —
-        necessarily batch-shaped: it retains every round to re-stitch the
-        whole prefix, and replays the final commit matrix through the
-        serving plane at the end."""
-        cfg = self.cfg
-        feedback = cfg.staleness_feedback
-        lat_cycle = EpochLatencyCycle(trace, max(n_epochs, 1))
-        rounds: list[_EpochRound] = []
-        sims: list[WANSimulator] = []
-        results = []
-        lags: list[tuple[float, int]] = []
-        views = view_next = None
-        pending_ups: dict[int, list[Update]] = {}
-        commit_ms = np.zeros((0, cfg.n_nodes))
-        stream = stitched = None
-        if feedback:
-            views = [DeltaCRDTStore(i) for i in range(cfg.n_nodes)]
-            view_next = np.zeros(cfg.n_nodes, dtype=int)
-        for e in range(n_epochs):
-            lat = lat_cycle[e]
-            if feedback:
-                self._advance_views(views, view_next, pending_ups,
-                                    lambda k, i, _c=commit_ms: float(_c[k, i]),
-                                    commit_ms.shape[0], e * cfg.epoch_ms)
-                lag = e - view_next
-                lags.append((float(lag.mean()) if lag.size else 0.0,
-                             int(lag.max()) if lag.size else 0))
-                snapshot = views
-            else:
-                snapshot = self.store
-            txns = generator.epoch_txns(e, txns_per_node, snapshot=snapshot)
-            rnd = self._prepare_epoch(e, txns, lat, views=views)
-            sim = WANSimulator(lat, self.bandwidth, loss=self.loss,
-                               rng=self.rng, verify=cfg.verify_schedules)
-            res = sim.run(rnd.schedule)
-            self.msg_matrix += res.msg_matrix
-            rounds.append(rnd)
-            sims.append(sim)
-            results.append(res)
-            if feedback:
-                pending_ups[e] = rnd.ups
-                # measured staleness for the *next* epoch's views; the last
-                # iteration's prefix is the full stream the stats consume
-                commit_ms, stream, stitched = self._stream_prefix(
-                    rounds, lat_cycle
-                )
-        if not rounds:
-            return None
-
-        if stream is None:
-            commit_ms, stream, stitched = self._stream_prefix(
-                rounds, lat_cycle
-            )
-        # per-epoch absolute commit marks in one grouped pass (the old
-        # per-epoch `finish_ms[epoch_of == k].max()` scan was quadratic)
-        epoch_of = np.array([t.epoch for t in stitched.transfers])
-        commit_marks = np.full(len(rounds), -np.inf)
-        np.maximum.at(commit_marks, epoch_of, stream.finish_ms)
-
-        prev_commit = 0.0
-        for k, (rnd, sim, res) in enumerate(zip(rounds, sims, results)):
-            commit = float(commit_marks[k])
-            wall = commit - prev_commit
-            prev_commit = commit
-            formula = max(cfg.epoch_ms, rnd.exec_ms, res.makespan_ms)
-            lag_mean, lag_max = lags[k] if feedback else (0.0, 0)
-            agg.on_epoch(
-                self._epoch_stats(
-                    rnd, sim, res,
-                    wall_ms=wall,
-                    pipeline_overlap_ms=formula - wall,
-                    stream_commit_ms=commit,
-                    view_lag_mean=lag_mean,
-                    view_lag_max=lag_max,
-                ),
-                EpochContext(epoch=k, commit_row=commit_ms[k],
-                             lat=lat_cycle[k]),
-            )
-
-        serve_stats = None
-        if cfg.serve is not None:
-            # the serving plane is a pure consumer of the measured timeline:
-            # per-node view-advance times (the same commit matrix the OCC
-            # feedback loop merges views at) + the trace RTTs for redirects.
-            # wall_ms covers the full client window even when the last
-            # commit lands inside it.
-            from ..serve.plane import simulate_serving
-
-            serve_stats = simulate_serving(
-                cfg.serve,
-                commit_ms,
-                lat_cycle,
-                cfg.epoch_ms,
-                wall_ms=max(prev_commit, n_epochs * cfg.epoch_ms),
-            )
-        return serve_stats
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,9 @@
 The staleness-feedback loop (``EngineConfig(staleness_feedback=True)``)
 needs each epoch's measured per-node commit times *before* it can execute
 the next epoch's transactions.  The original implementation re-simulated
-the stitched prefix every epoch (``GeoCluster._stream_prefix``) — exact,
-but O(E²) in simulated transfers, capping runs at tens of epochs.
+the stitched prefix every epoch (the ``stream_mode="resim"`` timing source
+of ``GeoCluster.run``) — exact, but O(E²) in simulated transfers, capping
+runs at tens of epochs.
 
 :class:`StreamingTimeline` owns the running event-engine state instead —
 the stitch frontier (:class:`~repro.core.schedule.StitchState`: per-node
@@ -197,10 +198,11 @@ class StreamingTimeline:
     def evict_commit_rows(self, before: int) -> None:
         """Release commit rows of epochs ``< before`` (monotone; clamped to
         the appended horizon).  Sound for the feedback loop once every
-        node's view has merged past them: ``_advance_views`` only ever
-        reads rows ``>= view_next.min()``, and an epoch's row is final the
-        moment it is appended (the admission theorem), so nothing will
-        update or reread a released row.  The memory is reclaimed lazily by
+        node's view has merged past them: ``advance_views`` (called from
+        ``GeoCluster.run``'s epoch loop) only ever reads rows ``>=
+        view_next.min()``, and an epoch's row is final the moment it is
+        appended (the admission theorem), so nothing will update or reread
+        a released row.  The memory is reclaimed lazily by
         the next capacity request (compact-or-grow)."""
         before = min(int(before), self._stitch.epoch)
         if before > self._evicted:

@@ -89,8 +89,9 @@ def reject_policy(staleness_ms: np.ndarray, bound: float):
 def view_epochs(commit_ms: np.ndarray, now_ms: float) -> np.ndarray:
     """Per-node count of epochs whose inbound transfers have delivered by
     ``now_ms`` — the epoch prefix each node's snapshot view has merged
-    (``GeoCluster._advance_views`` uses the identical ``<= now + eps``
-    convention, so serving sees exactly the OCC loop's views)."""
+    (:func:`repro.core.replication.advance_views` uses the identical
+    ``<= now + eps`` convention, so serving sees exactly the OCC loop's
+    views)."""
     return (commit_ms <= now_ms + _EPS).sum(axis=0)
 
 
@@ -291,7 +292,7 @@ def simulate_serving(
     """Serve every epoch's client read load against the measured views —
     a thin batch wrapper replaying a full commit matrix through
     :class:`ServingSink` (the results are identical by construction; the
-    incremental engine drives the sink directly).
+    engine drives the sink directly, and the tests compare against this).
 
     ``commit_ms`` is the ``(n_epochs, n_nodes)`` per-node commit-time
     matrix of the stitched streaming run (``node_commit_ms`` — its columns

@@ -14,10 +14,6 @@ Composes the jitted train step with:
   last checkpoint; duplicate replays are harmless because the optimizer
   state is versioned by ``step`` (applying the same step twice from the same
   checkpoint is deterministic and idempotent at the state level).
-
-The pre-control ``on_straggler`` callback is deprecated: it carried no
-typed payload and bypassed the strategy registry.  Pass ``control=`` a
-:class:`~repro.control.plane.ControlPlane` instead.
 """
 
 from __future__ import annotations
@@ -25,7 +21,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-import warnings
 from typing import Any, Callable
 
 import jax
@@ -96,7 +91,6 @@ class Trainer:
         data_cfg: DataConfig | None = None,
         *,
         control: "Any | None" = None,
-        on_straggler: Callable[["Trainer"], None] | None = None,
     ):
         """``control`` is a ``repro.control.ControlPlane``; the trainer
         subscribes for network events and, when the plane carries its own
@@ -116,15 +110,6 @@ class Trainer:
         self.monitor = StragglerMonitor(
             run_cfg.straggler_threshold, run_cfg.straggler_sustain
         )
-        if on_straggler is not None:
-            warnings.warn(
-                "Trainer(on_straggler=...) is deprecated; pass control= a "
-                "repro.control.ControlPlane and subscribe to its typed "
-                "NetworkEvents instead",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-        self.on_straggler = on_straggler
         self.control = control
         self.network_events: list[Any] = []
         self.sync_rebuilds = 0
@@ -276,16 +261,14 @@ class Trainer:
                     ):
                         self.control.step()  # probe -> damped replan -> events
                     # a step that compiled says nothing about the device's pace
-                    if not fresh and self.monitor.observe(dt):
-                        if self.control is not None:
-                            # sustained step-time degradation: event-driven
-                            # replan, effective immediately (not at the next
-                            # observation)
-                            self.control.force_replan(
-                                reason=f"straggler@step{self.step_idx}"
-                            )
-                        if self.on_straggler is not None:
-                            self.on_straggler(self)
+                    if (not fresh and self.monitor.observe(dt)
+                            and self.control is not None):
+                        # sustained step-time degradation: event-driven
+                        # replan, effective immediately (not at the next
+                        # observation)
+                        self.control.force_replan(
+                            reason=f"straggler@step{self.step_idx}"
+                        )
                 if cfg.ckpt_dir and self.step_idx % cfg.ckpt_every == 0:
                     with span("trainer.checkpoint", step=n):
                         self.save_ckpt()

@@ -429,9 +429,6 @@ def test_engine_owns_no_private_replanner():
     assert eng.control.replan_count >= 1
     assert eng.control.plan is not None
     assert rs.committed > 0
-    # the deprecated accessor warns but still reaches the same machinery
-    with pytest.warns(DeprecationWarning):
-        assert eng._replanner is eng.control.replanner
 
 
 def test_engine_binds_payload_planner_only_on_default_plane():
@@ -529,12 +526,3 @@ def test_trainer_straggler_trip_forces_immediate_replan(pod4_mesh):
     assert len(forced) >= 1  # the trip replanned without waiting a round
 
 
-def test_trainer_on_straggler_callback_is_deprecated(pod4_mesh):
-    from repro.train.trainer import Trainer
-
-    with pytest.warns(DeprecationWarning, match="on_straggler"):
-        tr = _mk_trainer(pod4_mesh, None)
-        Trainer(
-            tr.model_cfg, pod4_mesh, tr.tcfg, tr.run_cfg, tr.data_cfg,
-            on_straggler=lambda t: None,
-        )

@@ -116,7 +116,7 @@ def test_view_staleness_from_commit_matrix():
     # node1 merged {0,1} -> 10 ms behind, node2 merged nothing -> 30 ms
     assert list(view_epochs(_COMMIT, 30.0)) == [3, 2, 0]
     assert np.allclose(view_staleness_ms(_COMMIT, 30.0, 10.0), [0.0, 10.0, 30.0])
-    # boundary convention matches _advance_views: commit at exactly `now`
+    # boundary convention matches advance_views: commit at exactly `now`
     # counts as delivered
     assert list(view_epochs(np.array([[5.0]]), 5.0)) == [1]
 

@@ -26,7 +26,8 @@ from repro.train.train_step import TrainConfig
 from repro.train.trainer import FaultInjected, StragglerMonitor, Trainer, TrainerConfig
 
 
-def _mk_trainer(tmp_path, *, steps=8, sync="hier", mesh=None, seed=0):
+def _mk_trainer(tmp_path, *, steps=8, sync="hier", mesh=None, seed=0,
+                control=None):
     cfg = get_smoke_config("minitron-8b")
     mesh = mesh or make_small_mesh()
     tcfg = TrainConfig(
@@ -41,7 +42,7 @@ def _mk_trainer(tmp_path, *, steps=8, sync="hier", mesh=None, seed=0):
     )
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=32,
                           global_batch=8, seed=seed)
-    return Trainer(cfg, mesh, tcfg, run_cfg, data_cfg)
+    return Trainer(cfg, mesh, tcfg, run_cfg, data_cfg, control=control)
 
 
 def test_loss_decreases_and_checkpoints(tmp_path):
@@ -174,12 +175,18 @@ def test_straggler_monitor_damping():
 
 
 def test_straggler_triggers_replan_hook(tmp_path):
-    events = []
-    tr = _mk_trainer(tmp_path, steps=6)
+    from repro.control import ControlPlane, PlanChanged, TraceView
+
+    lat = np.array([[0.0, 10.0, 14.0, 10.0], [10.0, 0.0, 10.0, 14.0],
+                    [14.0, 10.0, 0.0, 10.0], [10.0, 14.0, 10.0, 0.0]])
+    cp = ControlPlane(TraceView(lat))
+    tr = _mk_trainer(tmp_path, steps=6, control=cp)
     tr.monitor = StragglerMonitor(threshold=0.0, sustain=1)  # trip every step
-    tr.on_straggler = lambda t: events.append(t.step_idx)
     tr.run()
-    assert len(events) >= 1
+    assert tr.monitor.trips >= 1
+    forced = [e for e in cp.events if isinstance(e, PlanChanged)
+              and e.reason.startswith("straggler@")]
+    assert len(forced) >= 1
 
 
 def test_synthetic_data_deterministic():
