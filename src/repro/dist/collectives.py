@@ -234,13 +234,24 @@ def _pod_mean(x: jnp.ndarray, axis: str, n_pods: int, order) -> jnp.ndarray:
 
 
 def _topk_mask(m: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Per-row mask selecting the ``k`` largest-|.| entries of ``m``."""
-    rows, chunk = m.shape
+    """Boolean per-row mask selecting the ``k`` largest-|.| entries of ``m``.
+
+    The entries ``top_k`` picks, built by a compare rather than a scatter of
+    its indices: every entry above the row's k-th value, and every entry
+    equal to it up to the column of the last index ``top_k`` returned
+    (``top_k`` puts the lower index first among equals).  ``|m|`` is
+    compared by its int32 bit pattern, a total order on non-negative floats
+    that ranks NaN above +inf, as ``top_k`` does.
+    """
+    chunk = m.shape[1]
     if k >= chunk:
-        return jnp.ones_like(m)
-    _, idx = jax.lax.top_k(jnp.abs(m), k)                      # (rows, k)
-    row_ids = jnp.repeat(jnp.arange(rows), k)
-    return jnp.zeros_like(m).at[row_ids, idx.ravel()].set(1.0)
+        return jnp.ones(m.shape, bool)
+    a = jnp.abs(m)
+    vals, idx = jax.lax.top_k(a, k)                            # (rows, k)
+    bits = jax.lax.bitcast_convert_type(a, jnp.int32)
+    kth = jax.lax.bitcast_convert_type(vals[:, k - 1:], jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    return (bits > kth) | ((bits == kth) & (col <= idx[:, k - 1:]))
 
 
 def chunked_topk_exchange(
@@ -256,8 +267,11 @@ def chunked_topk_exchange(
 
     The device-plane analogue of white-data filtering: per ``chunk``-sized
     block, only the ``density`` fraction of largest-magnitude entries of
-    ``grad + residual`` crosses the pod boundary; the rest stays in the new
-    residual and is *carried to the next step* (error feedback), so no task
+    ``grad + residual`` crosses the pod boundary: exactly
+    ``k = max(1, round(density * chunk))`` entries a row (the tail row
+    padded with zeros), the lower index first among equal magnitudes, as
+    ``jax.lax.top_k`` orders them.  The rest stays in the new residual and
+    is *carried to the next step* (error feedback), so no task
     signal is dropped — only deferred.  Returns ``(pmean_of_sent,
     new_residual)``.  With ``density=1.0`` this is exactly a ``pmean`` and
     the residual returns to zero.  ``order`` routes the reduction over an
@@ -276,8 +290,11 @@ def chunked_topk_exchange(
         flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.float32)])
     m = flat.reshape(-1, chunk)
     k = max(1, int(round(density * chunk)))
-    mask = _topk_mask(m, k)
-    sent = m * mask
+    pick = _topk_mask(m, k)
+    # ``m * mask`` to the bit: an unsent entry goes out as ``m * 0.0``, a
+    # zero of its own sign (NaN for an inf).  XLA rewrites a product with a
+    # converted boolean into a select of +0.0, so it is written out here.
+    sent = jnp.where(pick, m, m * 0.0)
     new_res = m - sent
     if order is not None:
         out = relay_psum(sent, axis, order=order) / len(order)

@@ -15,6 +15,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import (
     SyncConfig,
+    _topk_mask,
     chunked_topk_exchange,
     estimate_sync_bytes,
     relay_psum,
@@ -94,6 +95,116 @@ def test_chunked_topk_error_feedback_conserves(mesh):
     np.testing.assert_allclose(
         np.asarray(mean_sent * 0 + (acc - new_r) + new_r), np.asarray(acc), rtol=1e-6
     )
+
+
+def _scatter_mask(m, k):
+    """``top_k``'s indices scattered as 1.0 into zeros: the oracle for
+    :func:`_topk_mask`."""
+    rows, chunk = m.shape
+    if k >= chunk:
+        return jnp.ones_like(m)
+    _, idx = jax.lax.top_k(jnp.abs(m), k)
+    row_ids = jnp.repeat(jnp.arange(rows), k)
+    return jnp.zeros_like(m).at[row_ids, idx.ravel()].set(1.0)
+
+
+def _mask_rows(case, chunk, k):
+    """Rows of ``chunk`` f32 for one named case of the mask test."""
+    rng = np.random.default_rng(chunk * 1000 + k)
+    normal = rng.normal(size=(4, chunk)).astype(np.float32)
+    if case == "zeros":
+        return np.zeros((3, chunk), np.float32)
+    if case == "neg_zeros":
+        return np.full((3, chunk), -0.0, np.float32)
+    if case == "signed_zeros":
+        return np.where(rng.random((3, chunk)) < 0.5, 0.0, -0.0).astype(np.float32)
+    if case == "few_ints":
+        # few distinct magnitudes of either sign: ties straddle the k-th place
+        return rng.integers(-2, 3, size=(16, chunk)).astype(np.float32)
+    if case == "one_nan":
+        normal[:, chunk // 3] = np.nan
+        return normal
+    if case == "nans_over_k":
+        cols = rng.permutation(chunk)[: min(k + 3, chunk)]
+        normal[:, cols] = np.nan
+        return normal
+    if case == "pos_inf":
+        return np.full((2, chunk), np.inf, np.float32)
+    if case == "infs_and_ints":
+        x = rng.integers(-3, 4, size=(8, chunk)).astype(np.float32)
+        x[x == 3] = np.inf
+        x[x == -3] = -np.inf
+        return x
+    if case == "padded_tail":
+        # the exchange's last row: a leaf's tail, then zeros to the chunk
+        n = 3 * chunk + chunk // 3
+        flat = np.concatenate([rng.integers(-4, 5, size=n), np.zeros(4 * chunk - n)])
+        return flat.astype(np.float32).reshape(4, chunk)
+    raise ValueError(case)
+
+
+_MASK_CASES = ["zeros", "neg_zeros", "signed_zeros", "few_ints", "one_nan",
+               "nans_over_k", "pos_inf", "infs_and_ints", "padded_tail"]
+
+
+@pytest.mark.parametrize("case", _MASK_CASES)
+@pytest.mark.parametrize("chunk,k", [(16, 1), (16, 7), (16, 15), (2048, 205)])
+def test_topk_mask_matches_scatter_mask(case, chunk, k):
+    """The compare against each row's k-th value picks exactly the entries
+    ``top_k``'s indices name, tie for tie, NaN and inf included: k = 1,
+    k = chunk - 1 and granite's 205 of 2048."""
+    m = jnp.asarray(_mask_rows(case, chunk, k))
+    got = np.asarray(jax.jit(_topk_mask, static_argnums=1)(m, k))
+    want = np.asarray(jax.jit(_scatter_mask, static_argnums=1)(m, k))
+    assert got.dtype == bool
+    np.testing.assert_array_equal(got, want == 1.0)
+    assert (got.sum(axis=1) == k).all()
+
+
+def _scatter_exchange(grad, residual, *, axis, density, chunk, order):
+    """``chunked_topk_exchange`` with the scatter mask multiplied in: the
+    oracle for its bits."""
+    acc = grad + residual
+    n = acc.size
+    flat = jnp.concatenate([acc.ravel(), jnp.zeros(((-n) % chunk,), jnp.float32)])
+    m = flat.reshape(-1, chunk)
+    sent = m * _scatter_mask(m, max(1, int(round(density * chunk))))
+    new_res = m - sent
+    if order is not None:
+        out = relay_psum(sent, axis, order=order) / len(order)
+    else:
+        out = jax.lax.pmean(sent, axis)
+    return (out.ravel()[:n].reshape(acc.shape),
+            new_res.ravel()[:n].reshape(acc.shape))
+
+
+@pytest.mark.parametrize("order", [None, (1, 0)])
+@pytest.mark.parametrize("with_nonfinite", [False, True])
+def test_chunked_topk_exchange_bit_equal_to_scatter_oracle(mesh, order,
+                                                           with_nonfinite):
+    """``(out, new_res)`` are the same to the bit as with the scatter mask,
+    on ties, signed zeros, a padded tail row, and rows with more infs and
+    NaNs than k."""
+    rng = np.random.default_rng(4)
+    g = rng.integers(-3, 4, size=(5, 40)).astype(np.float32) * 0.5
+    g[g == 0] = np.where(rng.random((g == 0).sum()) < 0.5, 0.0, -0.0)
+    r = rng.normal(size=(5, 40)).astype(np.float32)
+    r[:, ::3] = -0.0
+    if with_nonfinite:
+        g[0, :12] = np.inf
+        g[1, 3:20:2] = -np.inf
+        g[2, 30:] = np.nan
+    kw = dict(axis="pod", density=0.25, chunk=32, order=order)
+
+    def body(g, r):
+        local = g * (1.0 + jax.lax.axis_index("pod").astype(jnp.float32))
+        return chunked_topk_exchange(local, r, **kw), _scatter_exchange(local, r, **kw)
+
+    (out, new_r), (out_o, new_r_o) = _podmap(mesh, body, 2)(g, r)
+    for a, b in ((out, out_o), (new_r, new_r_o)):
+        np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint32), np.asarray(b).view(np.uint32)
+        )
 
 
 def test_sync_gradients_strategies_agree_at_density_1(mesh):

@@ -12,6 +12,7 @@ one process at a time may load the TPU library.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,11 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs.registry import get_config
-from repro.dist.collectives import SyncConfig, shard_local_specs
+from repro.dist.collectives import (
+    SyncConfig,
+    chunked_topk_exchange,
+    shard_local_specs,
+)
 from repro.dist.sharding import param_specs
 from repro.kernels.crdt_merge import ops as crdt
 from repro.kernels.rglru_scan import ops as rglru
@@ -131,3 +136,23 @@ def test_pod_sync_compiles_with_pod_collectives(topo):
     assert "all-reduce" in pod
     assert any("f32[7680,2048]" in line for line in text.splitlines()
                if collectives_over(line, dict(mesh.shape), "pod"))
+
+
+def test_topk_exchange_compiles_without_scatter(topo):
+    """The geococo filter on ``pod=2``, over one chip's shard of a granite
+    expert leaf (7,680 rows of 2048, 205 kept a row), builds its mask with
+    no scatter: the only sort left is the one ``top_k`` lowers to."""
+    mesh = make_mesh((2, 2, 1), ("pod", "data", "model"), devices=topo.devices)
+    exchange = jax.shard_map(
+        lambda g, r: chunked_topk_exchange(g, r, axis="pod"),
+        mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+        axis_names={"pod"}, check_vma=False,
+    )
+    leaf = jax.ShapeDtypeStruct((7680, 2048), jnp.float32,
+                                sharding=NamedSharding(mesh, P()))
+    text = _hlo(exchange, leaf, leaf)
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*? (sort|scatter)\(", text, re.M)
+    assert [name for name, op in ops if op == "scatter"] == []
+    sorts = [name for name, op in ops if op == "sort"]
+    assert sorts and all(name.startswith("top_k") for name in sorts), sorts
+    assert "all-reduce" in collectives_over(text, dict(mesh.shape), "pod")
