@@ -283,7 +283,7 @@ def phase_trainer(seed: int, steps: int = 5) -> list[float]:
     batch0 = {k: jnp.asarray(v) for k, v in trainer.data.batch(0).items()}
 
     with jax.default_matmul_precision("highest"):
-        ref_fn = jax.jit(lambda p, b: loss_fn(cfg, p, b, jnp.float32))
+        ref_fn = jax.jit(lambda p, b: loss_fn(cfg, p, b, jnp.float32)[0])
 
         def ref_loss(params):
             # per sequence: same mean (equal lengths), a fraction of the memory

@@ -10,6 +10,20 @@ __all__ = ["MoEConfig", "MLAConfig", "Block", "ModelConfig", "ShapeSpec", "SHAPE
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts (+ shared ones).
+
+    ``scoring`` "softmax" picks the top-k of the softmax; "sigmoid"
+    (DeepSeek-V3) picks the top-k of sigmoid scores plus a selection bias,
+    with gates from the unbiased scores.  Either way the k gates are
+    normalised to sum to 1 and multiplied by ``routed_scale``.  The router
+    is ``n_experts`` wide; the layer holds ``n_held`` of them (0: all), the
+    first share of an expert-parallel deployment: capacity and positions
+    are reckoned over all ``n_experts``, and only the held ones' assignments
+    are computed.  ``balance_alpha`` weighs the sequence-wise balance loss;
+    ``bias_gamma`` > 0 gives the router a selection bias that moves by
+    ``bias_gamma * sign(mean load - load)`` after each step, outside the
+    optimizer (arXiv 2412.19437 §2.1.2)."""
+
     n_experts: int
     top_k: int
     d_expert: int                 # per-expert FFN hidden dim
@@ -17,17 +31,33 @@ class MoEConfig:
     d_shared: int = 0             # shared-expert hidden dim (0 -> d_expert)
     capacity_factor: float = 1.25
     router_noise: float = 0.0
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scale: float = 1.0
+    n_held: int = 0
+    balance_alpha: float = 0.0
+    bias_gamma: float = 0.0
 
     @property
     def shared_hidden(self) -> int:
         return self.d_shared or self.d_expert
 
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def tracks_load(self) -> bool:
+        """The layer reports its loads and balance term (a bias rule or a
+        balance loss needs them)."""
+        return self.bias_gamma > 0 or self.balance_alpha > 0
+
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-style Multi-head Latent Attention dimensions."""
+    """DeepSeek-style Multi-head Latent Attention dimensions.
+    ``q_lora_rank`` ``None``: queries are projected directly, with no latent."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64

@@ -3,7 +3,12 @@
 
 61L d_model=7168 128H d_expert=2048 vocab=129280.  First 3 layers dense
 (d_ff 18432) per the DeepSeek-V3 architecture; the MTP head is out of scope
-(noted in DESIGN.md).
+(noted in DESIGN.md).  Routing as published: sigmoid scores plus a
+selection bias moved by 0.001 a step (aux-loss-free balancing), gates
+normalised over the 8 and scaled by 2.5, and a sequence-wise balance term
+of weight 0.0001.  Its group-limited routing (``n_group`` 8, ``topk_group``
+4: a token's experts come from its 4 best of 8 groups) is not modelled:
+the top-8 is taken over all 256.
 """
 
 from .base import Block, MLAConfig, ModelConfig, MoEConfig
@@ -25,7 +30,8 @@ def config() -> ModelConfig:
         blocks_pattern=(Block("mla", "moe"),),
         moe=MoEConfig(
             n_experts=256, top_k=8, d_expert=2048, n_shared=1, d_shared=2048,
-            capacity_factor=1.25,
+            capacity_factor=1.25, scoring="sigmoid", routed_scale=2.5,
+            balance_alpha=1e-4, bias_gamma=1e-3,
         ),
         mla=MLAConfig(
             q_lora_rank=1536, kv_lora_rank=512,
@@ -49,7 +55,8 @@ def smoke_config() -> ModelConfig:
         # high capacity factor: no token drops -> decode/full-forward parity
         # is exactly testable (drops are a capacity artifact, not semantics)
         moe=MoEConfig(n_experts=8, top_k=2, d_expert=32, n_shared=1, d_shared=32,
-                      capacity_factor=8.0),
+                      capacity_factor=8.0, scoring="sigmoid", routed_scale=2.5,
+                      balance_alpha=1e-4, bias_gamma=1e-3),
         mla=MLAConfig(
             q_lora_rank=32, kv_lora_rank=16,
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,

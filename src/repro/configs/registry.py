@@ -10,6 +10,7 @@ from . import (
     hubert_xlarge,
     llama32_vision_90b,
     minitron_8b,
+    moonlight_16b_a3b,
     qwen2_5_32b,
     recurrentgemma_9b,
     rwkv6_7b,
@@ -29,6 +30,7 @@ _MODULES = {
         hubert_xlarge,
         recurrentgemma_9b,
         llama32_vision_90b,
+        moonlight_16b_a3b,
     )
 }
 

@@ -8,6 +8,7 @@ heterogeneous stacks are compiled compactly via the scan partition
 Public API:
     init_params(cfg, key)                      -> params pytree
     forward(cfg, params, batch, cache=None)    -> (logits, new_cache)
+    forward_with_aux(cfg, params, batch)       -> (logits, MoE routing records)
     init_cache(cfg, batch, max_len)            -> decode cache pytree
     param_count(cfg)                           -> int (via eval_shape)
 """
@@ -41,7 +42,8 @@ from .layers import (
     unembed_apply,
 )
 
-__all__ = ["init_params", "forward", "init_cache", "param_count", "num_params"]
+__all__ = ["init_params", "forward", "forward_with_aux", "init_cache", "param_count",
+           "num_params", "moe_records", "with_bias_step"]
 
 # mixers whose work the "attn" named scope covers (the profile's attention)
 _ATTN_MIXERS = ("attn", "attn_local", "attn_cross", "mla")
@@ -80,10 +82,7 @@ def _block_init(key, cfg: ModelConfig, block: Block) -> Params:
         p["ffn"] = swiglu_init(k2, d, cfg.d_ff)
     elif block.ffn == "moe":
         assert cfg.moe is not None
-        p["ffn"] = moe_mod.moe_init(
-            k2, d, cfg.moe.n_experts, cfg.moe.d_expert,
-            n_shared=cfg.moe.n_shared, d_shared=cfg.moe.d_shared,
-        )
+        p["ffn"] = moe_mod.moe_init(k2, d, cfg.moe)
     elif block.ffn == "rwkv_cmix":
         p["ffn"] = rwkv_mod.rwkv_cmix_init(k2, d, cfg.d_ff)
     elif block.ffn != "none":
@@ -125,6 +124,7 @@ def _block_apply(
     img_ctx: jnp.ndarray | None = None,
     cache: Params | None = None,
 ):
+    """One residual block: ``(x, new cache, MoE routing record or None)``."""
     eps = cfg.norm_eps
     h = rmsnorm_apply(p["norm1"], x, eps=eps)
     new_cache = cache
@@ -155,7 +155,7 @@ def _block_apply(
         elif block.mixer == "mla":
             y, new_mla_cache = mla_mod.mla_apply(
                 p["mixer"], h, n_heads=cfg.n_heads, mla=cfg.mla,
-                causal=cfg.causal, rope_theta=cfg.rope_theta, cache=cache,
+                causal=cfg.causal, rope_theta=cfg.rope_theta, eps=eps, cache=cache,
             )
             if cache is not None:
                 new_cache = new_mla_cache
@@ -177,18 +177,17 @@ def _block_apply(
             raise ValueError(block.mixer)
     x = x + y
 
+    aux = None
     if block.ffn == "none":
-        return x, new_cache
+        return x, new_cache, aux
     h2 = rmsnorm_apply(p["norm2"], x, eps=eps)
     if block.ffn == "dense":
         from .layers import swiglu_apply
 
         x = x + swiglu_apply(p["ffn"], h2)
     elif block.ffn == "moe":
-        x = x + moe_mod.moe_apply(
-            p["ffn"], h2, top_k=cfg.moe.top_k,
-            capacity_factor=cfg.moe.capacity_factor,
-        )
+        y2, aux = moe_mod.moe_apply(p["ffn"], h2, cfg.moe)
+        x = x + y2
     elif block.ffn == "rwkv_cmix":
         y2, new_c = rwkv_mod.rwkv_cmix_apply(
             p["ffn"], h2,
@@ -198,7 +197,7 @@ def _block_apply(
         if cache is not None and block.mixer == "rwkv":
             new_cache = dict(new_cache)
             new_cache["cmix"] = new_c
-    return x, new_cache
+    return x, new_cache, aux
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +276,24 @@ def forward(
     optionally overrides the vocab lookup (the trainer supplies an explicitly
     sharded implementation; XLA's gather partitioner is not trusted with it).
     """
+    logits, new_cache, _ = _run(cfg, params, batch, cache, compute_dtype,
+                                act_constrain, embed_fn)
+    return logits, new_cache
+
+
+def forward_with_aux(cfg: ModelConfig, params: Params, batch: dict[str, jnp.ndarray], *,
+                     compute_dtype=jnp.bfloat16, act_constrain=None, embed_fn=None):
+    """``forward`` without a cache, returning ``(logits, aux)``: each MoE
+    block's routing record (``moe.route``; ``None`` for other blocks and
+    unless ``cfg.moe.tracks_load``), laid out as the blocks of ``params``
+    are: ``{"prefix": (...), "scan": (...), "suffix": (...)}``, a scanned
+    record with a leading layer axis."""
+    logits, _, aux = _run(cfg, params, batch, None, compute_dtype, act_constrain,
+                          embed_fn)
+    return logits, aux
+
+
+def _run(cfg, params, batch, cache, compute_dtype, act_constrain, embed_fn):
     prefix, n_scan, pattern, suffix = cfg.scan_partition()
     ac = act_constrain if act_constrain is not None else (lambda x: x)
 
@@ -293,30 +310,36 @@ def forward(
         img_ctx = img_ctx.astype(compute_dtype)
 
     new_cache: Params = {"prefix": [], "suffix": []} if cache is not None else None
+    aux: Params = {"prefix": [], "suffix": []}
 
     def run_block(blk, p, xx, c):
-        xx, nc = _block_apply(cfg, blk, p, ac(xx), img_ctx=img_ctx, cache=c)
-        return ac(xx), nc
+        xx, nc, a = _block_apply(cfg, blk, p, ac(xx), img_ctx=img_ctx, cache=c)
+        return ac(xx), nc, a
+
+    # the unrolled blocks are rematerialized as the scanned ones are
+    edge_block = jax.checkpoint(run_block, static_argnums=0) if cfg.remat else run_block
 
     for i, blk in enumerate(prefix):
         c = cache["prefix"][i] if cache is not None else None
-        x, nc = run_block(blk, params["prefix"][i], x, c)
+        x, nc, a = edge_block(blk, params["prefix"][i], x, c)
+        aux["prefix"].append(a)
         if cache is not None:
             new_cache["prefix"].append(nc)
 
     if n_scan > 0:
         def superblock(xx, args):
             p_stack, c_stack = args
-            ncs = []
+            ncs, auxs = [], []
             for j, blk in enumerate(pattern):
                 c = c_stack[j] if c_stack is not None else None
-                xx, nc = run_block(blk, p_stack[j], xx, c)
+                xx, nc, a = run_block(blk, p_stack[j], xx, c)
                 ncs.append(nc)
-            return xx, (tuple(ncs) if c_stack is not None else None)
+                auxs.append(a)
+            return xx, (tuple(ncs) if c_stack is not None else None, tuple(auxs))
 
         body = jax.checkpoint(superblock) if cfg.remat else superblock
         c_scan = cache["scan"] if cache is not None else None
-        x, scan_caches = jax.lax.scan(
+        x, (scan_caches, aux["scan"]) = jax.lax.scan(
             body, x, (params["scan"], c_scan)
         )
         if cache is not None:
@@ -324,7 +347,8 @@ def forward(
 
     for i, blk in enumerate(suffix):
         c = cache["suffix"][i] if cache is not None else None
-        x, nc = run_block(blk, params["suffix"][i], x, c)
+        x, nc, a = edge_block(blk, params["suffix"][i], x, c)
+        aux["suffix"].append(a)
         if cache is not None:
             new_cache["suffix"].append(nc)
 
@@ -337,7 +361,28 @@ def forward(
     if cache is not None:
         new_cache["prefix"] = tuple(new_cache["prefix"])
         new_cache["suffix"] = tuple(new_cache["suffix"])
-    return logits, new_cache
+    aux["prefix"], aux["suffix"] = tuple(aux["prefix"]), tuple(aux["suffix"])
+    return logits, new_cache, aux
+
+
+def moe_records(aux: Params) -> list[Params]:
+    """The routing records of ``forward_with_aux``'s ``aux``, block by block
+    (a scanned one stacked over its layers)."""
+    return [r for part in ("prefix", "scan", "suffix") for r in aux.get(part, ())
+            if r is not None]
+
+
+def with_bias_step(cfg: ModelConfig, params: Params, aux: Params) -> Params:
+    """``params`` with each MoE block's selection bias moved by its loads
+    (``moe.bias_step``; arXiv 2412.19437 §2.1.2)."""
+    def part(blocks, records):
+        return tuple(
+            b if r is None else {**b, "ffn": {**b["ffn"], "bias": moe_mod.bias_step(
+                b["ffn"]["bias"], r["load"], cfg.moe.bias_gamma)}}
+            for b, r in zip(blocks, records))
+
+    return {**params, **{k: part(params[k], aux[k]) for k in ("prefix", "scan", "suffix")
+                         if k in aux}}
 
 
 def param_count(cfg: ModelConfig) -> int:

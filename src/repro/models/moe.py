@@ -8,7 +8,10 @@ as gather/scatter so compiled FLOPs reflect *active* expert compute
 roofline numbers honest.
 
 Expert weights are stacked (E, d, d_ff) so expert parallelism is a plain
-sharding annotation on the leading axis.
+sharding annotation on the leading axis.  A layer may hold fewer experts
+than its router is wide (``MoEConfig.n_held``): the first share of an
+expert-parallel deployment, whose capacity and positions are still reckoned
+over every expert.  Both paths route through :func:`route`.
 """
 
 from __future__ import annotations
@@ -18,32 +21,29 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..configs.base import MoEConfig
 from .layers import Params, dense_apply, dense_init
 
-__all__ = ["moe_init", "moe_apply"]
+__all__ = ["moe_init", "moe_apply", "route", "bias_step"]
 
 
-def moe_init(
-    key,
-    d_model: int,
-    n_experts: int,
-    d_expert: int,
-    *,
-    n_shared: int = 0,
-    d_shared: int = 0,
-    dtype=jnp.float32,
-) -> Params:
+def moe_init(key, d_model: int, moe: MoEConfig, *, dtype=jnp.float32) -> Params:
+    """Router over ``moe.n_experts``, weights of the ``moe.held`` experts, a
+    zero selection bias where the config has a bias rule, shared experts."""
     kr, ki, kg, ko, ks = jax.random.split(key, 5)
     scale_in = 1.0 / math.sqrt(d_model)
     scale_out = 0.02 / math.sqrt(2)
+    e, f = moe.held, moe.d_expert
     p = {
-        "router": dense_init(kr, d_model, n_experts, scale=0.02, dtype=dtype),
-        "wi": jax.random.normal(ki, (n_experts, d_model, d_expert), dtype) * scale_in,
-        "wg": jax.random.normal(kg, (n_experts, d_model, d_expert), dtype) * scale_in,
-        "wo": jax.random.normal(ko, (n_experts, d_expert, d_model), dtype) * scale_out,
+        "router": dense_init(kr, d_model, moe.n_experts, scale=0.02, dtype=dtype),
+        "wi": jax.random.normal(ki, (e, d_model, f), dtype) * scale_in,
+        "wg": jax.random.normal(kg, (e, d_model, f), dtype) * scale_in,
+        "wo": jax.random.normal(ko, (e, f, d_model), dtype) * scale_out,
     }
-    if n_shared > 0:
-        d_sh = (d_shared or d_expert) * n_shared
+    if moe.bias_gamma > 0:
+        p["bias"] = jnp.zeros((moe.n_experts,), dtype)
+    if moe.n_shared > 0:
+        d_sh = moe.shared_hidden * moe.n_shared
         k1, k2, k3 = jax.random.split(ks, 3)
         p["shared"] = {
             "wi": dense_init(k1, d_model, d_sh, dtype=dtype),
@@ -53,34 +53,94 @@ def moe_init(
     return p
 
 
-def moe_apply(
-    p: Params,
-    x: jnp.ndarray,                  # (B, S, d)
-    *,
-    top_k: int,
-    capacity_factor: float = 1.25,
-    return_aux: bool = False,
-):
-    """Top-k MoE.  Under a distribution context with a model axis that
-    divides the expert count, dispatch runs expert-parallel inside a manual
-    shard_map (each device computes only its local experts; partial outputs
-    psum over `model`) — both for performance and because XLA's SPMD
-    scatter partitioner cannot be trusted with sharded dispatch on CPU."""
+def moe_apply(p: Params, x: jnp.ndarray, moe: MoEConfig):
+    """Top-k MoE on ``x`` (B, S, d).  Returns ``(out, aux)``; ``aux`` is
+    ``None`` unless ``moe.tracks_load`` (see :func:`route`).
+
+    Under a distribution context with a model axis above 1, dispatch runs
+    expert-parallel inside a manual shard_map (each device computes only
+    its local experts; partial outputs psum over `model`) — both for
+    performance and because XLA's SPMD scatter partitioner cannot be
+    trusted with sharded dispatch on CPU."""
     from ..dist import context as dist_context
 
-    e = p["wi"].shape[0]
     ctx = dist_context.current()
-    if not return_aux and ctx is not None and ctx.model_size > 1:
-        return _moe_apply_manual_ep(p, x, top_k=top_k,
-                                    capacity_factor=capacity_factor, ctx=ctx)
-    return _moe_apply_dense_dispatch(
-        p, x, top_k=top_k, capacity_factor=capacity_factor,
-        return_aux=return_aux,
-    )
+    if ctx is not None and ctx.model_size > 1:
+        return _moe_apply_manual_ep(p, x, moe, ctx=ctx), None
+    return _moe_apply_dense_dispatch(p, x, moe)
 
 
-def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
-                         capacity_factor: float, ctx):
+def route(p: Params, xf: jnp.ndarray, moe: MoEConfig, *, seqs: int = 1,
+          lo=0, hi=None):
+    """Top-k routing of the tokens ``xf`` (T, d), ``seqs`` sequences of
+    equal length, over the router's ``E`` experts.
+
+    Returns ``(gate, expert, pos, keep, capacity, aux)``, the first four
+    (T, K): each assignment's gate (normalised over the k chosen, times
+    ``routed_scale``), its expert's index within the held range ``[lo,
+    hi)`` (default: every expert), its position in that expert's buffer
+    (clamped), and whether it is held and among the first ``capacity``
+    assignments of its expert in (token, k) order over all ``E``.
+
+    ``aux`` (``moe.tracks_load`` only) holds ``load``, each expert's
+    assignments before capacity (E,); ``held`` and ``kept``, the held
+    assignments and those capacity kept; ``balance``, the sequence-wise
+    balance loss ``alpha * sum_i f_i P_i`` averaged over sequences
+    (arXiv 2412.19437 eq. 17-20), ``f_i`` from the experts each token is
+    routed to.  Only ``balance`` carries a gradient, through ``P_i``."""
+    t = xf.shape[0]
+    e = p["router"]["w"].shape[1]
+    k = moe.top_k
+    f32 = jnp.float32
+    with jax.named_scope("moe_router"):
+        logits = (xf.astype(f32) @ p["router"]["w"].astype(f32)).astype(f32)
+        if moe.scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)                 # (T, E)
+            gate, idx = jax.lax.top_k(scores, k)                     # (T, K)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            sel = scores + p["bias"].astype(f32) if "bias" in p else scores
+            _, idx = jax.lax.top_k(sel, k)
+            gate = jnp.take_along_axis(scores, idx, axis=-1)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+        if moe.routed_scale != 1.0:
+            gate = gate * moe.routed_scale
+
+        capacity = max(1, int(moe.capacity_factor * k * t / e))
+        # position of each (token, k) assignment within its expert's queue
+        onehot = jax.nn.one_hot(idx, e, dtype=jnp.int32)            # (T, K, E)
+        flat_oh = onehot.reshape(t * k, e)
+        pos_in_expert = jnp.cumsum(flat_oh, axis=0) * flat_oh       # rank+1 where assigned
+        pos = (pos_in_expert.max(axis=-1) - 1).reshape(t, k)        # (T, K)
+        keep = pos < capacity
+        if hi is None and lo == 0:
+            expert, held = idx, None
+        else:
+            held = (idx >= lo) & (idx < hi)
+            keep = keep & held
+            expert = jnp.where(held, idx - lo, 0)
+        pos = jnp.where(keep, pos, capacity - 1)                    # clamp; masked by keep
+
+        aux = None
+        if moe.tracks_load:
+            counts = onehot.reshape(seqs, t // seqs * k, e).sum(1).astype(f32)  # (seqs, E)
+            f = counts * (e / (k * (t // seqs)))
+            share = scores / scores.sum(-1, keepdims=True)
+            p_mean = share.reshape(seqs, t // seqs, e).mean(1)
+            aux = {"load": counts.sum(0),
+                   "held": jnp.asarray(t * k if held is None else held.sum(), f32),
+                   "kept": keep.sum().astype(f32),
+                   "balance": moe.balance_alpha * (f * p_mean).sum(-1).mean()}
+    return gate, expert, pos, keep, capacity, aux
+
+
+def bias_step(bias: jnp.ndarray, load: jnp.ndarray, gamma: float) -> jnp.ndarray:
+    """The selection bias after one step: each expert's moves by ``gamma``
+    towards the mean load, ``gamma * sign(mean load - load)``."""
+    return bias + gamma * jnp.sign(load.mean(-1, keepdims=True) - load).astype(bias.dtype)
+
+
+def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, moe: MoEConfig, *, ctx):
     """Expert parallelism: experts over `model`, tokens over `data`, expert
     weights FSDP'd over `data` and all-gathered per layer inside the manual
     region (the scan-over-layers keeps exactly one gather alive at a time).
@@ -93,26 +153,30 @@ def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
     """
     from jax.sharding import PartitionSpec as P
 
+    if moe.tracks_load:
+        raise NotImplementedError(
+            "loads and the balance term of an expert-parallel layer need a sum "
+            "over the token shards; no path runs it")
     b, s, d = x.shape
-    e = p["wi"].shape[0]
+    e_held = p["wi"].shape[0]
     t = b * s
+    top_k = moe.top_k
     dm = ctx.model_size
     dd = ctx.data_size
-    # pad the expert dim to a multiple of the model axis (dummy experts hold
-    # zero weights and are never routed to: the router has only `e` outputs)
-    e_pad = -(-e // dm) * dm
+    # pad the held experts to a multiple of the model axis (dummy experts
+    # hold zero weights and are never routed to: routing keeps indices
+    # under the held count)
+    e_pad = -(-e_held // dm) * dm
     e_local = e_pad // dm
     shard_tokens = dd > 1 and t % dd == 0
-    t_local = t // dd if shard_tokens else t
-    capacity = max(1, int(capacity_factor * top_k * t_local / e))
     fsdp_w = dd > 1 and d % dd == 0
     compute_dtype = x.dtype
     f32 = jnp.float32
 
     def pad_experts(w):
-        if e_pad == e:
+        if e_pad == e_held:
             return w
-        return jnp.pad(w, ((0, e_pad - e), (0, 0), (0, 0)))
+        return jnp.pad(w, ((0, e_pad - e_held), (0, 0), (0, 0)))
 
     # per-shard expert offsets as a model-sharded iota (avoids axis_index,
     # whose lowering re-binds the outer manual pod axis)
@@ -134,24 +198,9 @@ def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
         lo = off[0]
         tl = xf.shape[0]
 
-        with jax.named_scope("moe_router"):
-            logits = (xf32 @ router_w).astype(f32)
-            probs = jax.nn.softmax(logits, axis=-1)                  # (Tl, E)
-            gate_vals, expert_idx = jax.lax.top_k(probs, top_k)      # (Tl, K)
-            gate_vals = gate_vals / jnp.maximum(
-                gate_vals.sum(-1, keepdims=True), 1e-9
-            )
-            onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)
-            pos_in_expert = (
-                jnp.cumsum(onehot.reshape(tl * top_k, e), axis=0)
-                * onehot.reshape(tl * top_k, e)
-            )
-            pos = (pos_in_expert.max(axis=-1) - 1).reshape(tl, top_k)
-            keep = pos < capacity
-            is_local = (expert_idx >= lo) & (expert_idx < lo + e_local)
-            keep_l = keep & is_local
-            le = jnp.where(is_local, expert_idx - lo, 0)             # (Tl, K)
-            pos_c = jnp.where(keep_l, pos, capacity - 1)
+        gate_vals, le, pos_c, keep_l, capacity, _ = route(
+            {"router": {"w": router_w}}, xf32, moe,
+            lo=lo, hi=jnp.minimum(lo + e_local, e_held))
 
         with jax.named_scope("moe_dispatch"):
             buf = jnp.zeros((e_local, capacity, d), compute_dtype)
@@ -191,7 +240,7 @@ def _moe_apply_manual_ep(p: Params, x: jnp.ndarray, *, top_k: int,
     ).astype(x.dtype)
 
     if "shared" in p:
-        with jax.named_scope("moe_experts"):
+        with jax.named_scope("moe_experts"), jax.named_scope("moe_shared"):
             out = out + _shared_experts(p["shared"], xf)
     return out.reshape(b, s, d)
 
@@ -201,38 +250,24 @@ def _shared_experts(sh: Params, xf: jnp.ndarray) -> jnp.ndarray:
     return dense_apply(sh["wo"], hs)
 
 
-def _moe_apply_dense_dispatch(
-    p: Params,
-    x: jnp.ndarray,
-    *,
-    top_k: int,
-    capacity_factor: float = 1.25,
-    return_aux: bool = False,
-):
+def _moe_apply_dense_dispatch(p: Params, x: jnp.ndarray, moe: MoEConfig, *, lo: int = 0):
+    """One device's experts: those held, ``p["wi"].shape[0]`` of them from
+    expert ``lo`` of the router (the layer's share of an expert-parallel
+    deployment).  Returns ``(out, aux)``."""
     b, s, d = x.shape
-    e = p["wi"].shape[0]
+    e_held = p["wi"].shape[0]
+    top_k = moe.top_k
     t = b * s
     xf = x.reshape(t, d)
-
-    with jax.named_scope("moe_router"):
-        logits = (xf @ p["router"]["w"].astype(jnp.float32)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-        gate_vals, expert_idx = jax.lax.top_k(probs, top_k)         # (T, K)
-        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-        capacity = max(1, int(capacity_factor * top_k * t / e))
-        # position of each (token, k) assignment within its expert's queue
-        onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.int32)     # (T, K, E)
-        flat_oh = onehot.reshape(t * top_k, e)
-        pos_in_expert = jnp.cumsum(flat_oh, axis=0) * flat_oh       # rank+1 where assigned
-        pos = (pos_in_expert.max(axis=-1) - 1).reshape(t, top_k)    # (T, K)
-        keep = pos < capacity
+    whole = lo == 0 and e_held == p["router"]["w"].shape[1]
+    gate_vals, expert_idx, pos, keep, capacity, aux = route(
+        p, xf, moe, seqs=b, lo=lo, hi=None if whole else lo + e_held)
 
     # dispatch: scatter token vectors into (E, C, d) buffers
     with jax.named_scope("moe_dispatch"):
-        buf = jnp.zeros((e, capacity, d), xf.dtype)
+        buf = jnp.zeros((e_held, capacity, d), xf.dtype)
         flat_e = expert_idx.reshape(-1)
-        flat_pos = jnp.where(keep, pos, capacity - 1).reshape(-1)   # clamp; masked below
+        flat_pos = pos.reshape(-1)
         flat_keep = keep.reshape(-1)
         src = jnp.repeat(xf, top_k, axis=0) * flat_keep[:, None].astype(xf.dtype)
         buf = buf.at[flat_e, flat_pos].add(src)
@@ -250,15 +285,7 @@ def _moe_apply_dense_dispatch(
         out = out_tok.reshape(t, top_k, d).sum(axis=1)
 
     if "shared" in p:
-        with jax.named_scope("moe_experts"):
+        with jax.named_scope("moe_experts"), jax.named_scope("moe_shared"):
             out = out + _shared_experts(p["shared"], xf)
 
-    out = out.reshape(b, s, d)
-    if not return_aux:
-        return out
-    # load-balance aux loss (Switch): E * sum_e f_e * p_e
-    frac_tokens = onehot.astype(jnp.float32).sum(axis=(0, 1)) / (t * top_k)
-    frac_probs = probs.mean(axis=0)
-    aux = e * jnp.sum(frac_tokens * frac_probs)
-    dropped = 1.0 - flat_keep.astype(jnp.float32).mean()
-    return out, {"aux_loss": aux, "drop_rate": dropped}
+    return out.reshape(b, s, d), aux

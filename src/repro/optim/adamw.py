@@ -56,7 +56,11 @@ def global_norm(tree: Any) -> jnp.ndarray:
 def adamw_update(
     params: Any, grads: Any, state: dict, cfg: AdamWConfig
 ) -> tuple[Any, dict, dict]:
-    """One optimizer step.  Returns (new_params, new_state, metrics)."""
+    """One optimizer step.  Returns (new_params, new_state, metrics).
+
+    A leaf whose gradient is ``None`` is left as it is, and its moments
+    too: neither Adam nor weight decay touches it (a router's selection
+    bias, which moves by its own rule)."""
     with jax.named_scope("adam"):
         return _adamw_update(params, grads, state, cfg)
 
@@ -70,6 +74,8 @@ def _adamw_update(params, grads, state, cfg):
     b2c = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
     def upd(p, g, m, v):
+        if g is None:
+            return p, m, v
         g = g.astype(jnp.float32) * scale
         m32 = m.astype(jnp.float32) * cfg.b1 + (1 - cfg.b1) * g
         v32 = v.astype(jnp.float32) * cfg.b2 + (1 - cfg.b2) * jnp.square(g)

@@ -35,7 +35,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs.base import ModelConfig, ShapeSpec
 from ..dist.collectives import SyncConfig, shard_local_specs, sync_gradients
 from ..dist.sharding import param_shardings, param_specs
-from ..models.model import forward, init_cache, init_params
+from ..models.model import (forward, forward_with_aux, init_cache, init_params, moe_records,
+                            with_bias_step)
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
 
 __all__ = [
@@ -258,17 +259,44 @@ def _act_constrain(mesh: Mesh, *, seq_parallel: bool = False, over_pod: bool = T
 
 def loss_fn(cfg: ModelConfig, params, batch, compute_dtype=jnp.bfloat16,
             act_constrain=None, *, embed_fn=None, logp_constrain=None):
-    """Mean next-token cross-entropy.  ``embed_fn`` replaces the vocabulary
-    lookup (see ``forward``); ``logp_constrain`` pins the log-probabilities
-    before the label pick."""
-    logits, _ = forward(cfg, params, batch, compute_dtype=compute_dtype,
-                        act_constrain=act_constrain, embed_fn=embed_fn)
+    """``(loss, aux)``: the mean next-token cross-entropy plus every MoE
+    layer's balance term, and the routing records (``forward_with_aux``).
+    ``embed_fn`` replaces the vocabulary lookup (see ``forward``);
+    ``logp_constrain`` pins the log-probabilities before the label pick."""
+    logits, aux = forward_with_aux(cfg, params, batch, compute_dtype=compute_dtype,
+                                   act_constrain=act_constrain, embed_fn=embed_fn)
     labels = batch["labels"]
     with jax.named_scope("logits"):
         lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         if logp_constrain is not None:
             lp = logp_constrain(lp)
-        return -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0].mean()
+        loss = -jnp.take_along_axis(lp, labels[..., None], axis=-1)[..., 0].mean()
+    records = moe_records(aux)
+    if records:
+        loss = loss + sum(r["balance"].sum() for r in records)
+    return loss, aux
+
+
+def routing_counters(aux) -> dict:
+    """``moe_load_peak``: the most any expert of any MoE layer was routed,
+    over its layer's mean load, before capacity; ``moe_drop_share``: the
+    share of the held assignments that capacity dropped.  Empty for a
+    model whose layers record no routing."""
+    records = moe_records(aux)
+    if not records:
+        return {}
+    peak = max(jnp.max(r["load"] / r["load"].mean(-1, keepdims=True)) for r in records)
+    held = sum(r["held"].sum() for r in records)
+    kept = sum(r["kept"].sum() for r in records)
+    return {"moe_load_peak": peak, "moe_drop_share": 1.0 - kept / held}
+
+
+def _without_bias(grads):
+    """The gradient with each router's selection bias left out (``None``):
+    the bias moves by its own rule, not by the optimizer."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, g: None if [getattr(k, "key", None) for k in path[-2:]]
+        == ["ffn", "bias"] else g, grads)
 
 
 # ---------------------------------------------------------------------------
@@ -404,9 +432,10 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
         return loss_fn(cfg, p, b, tcfg.compute_dtype, ac, **lookups)
 
     def loss_and_grads(params, batch):
+        """``((loss, routing records), grads)``."""
         if n_micro == 1:
             b = _constrain_batch(batch, mesh, over_pod=over_pod)
-            return jax.value_and_grad(lambda p: loss(p, b))(params)
+            return jax.value_and_grad(lambda p: loss(p, b), has_aux=True)(params)
         # gradient accumulation: one fwd/bwd per microbatch; only the
         # accumulated gradient crosses the pod boundary (per-step sync
         # frequency unchanged — the paper's epoch semantics)
@@ -419,36 +448,54 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
         def mb_step(carry, mbatch):
             gsum, lsum = carry
             b = _constrain_batch(mbatch, mesh, over_pod=over_pod)
-            l, g = jax.value_and_grad(lambda p: loss(p, b))(params)
+            (l, aux), g = jax.value_and_grad(lambda p: loss(p, b), has_aux=True)(params)
             gsum = jax.tree.map(lambda a, x: a + x.astype(jnp.float32), gsum, g)
-            return (gsum, lsum + l), None
+            return (gsum, lsum + l), aux
 
-        (gsum, lsum), _ = jax.lax.scan(
+        (gsum, lsum), auxs = jax.lax.scan(
             mb_step, (g0, jnp.zeros((), jnp.float32)), micro
         )
         grads = jax.tree.map(lambda g, p: (g / n_micro).astype(p.dtype), gsum, params)
-        return lsum / n_micro, grads
+        # the microbatches' records: loads and counts summed
+        aux = jax.tree.map(lambda a: a.sum(0), auxs)
+        return (lsum / n_micro, aux), grads
 
+    bias_rule = cfg.moe is not None and cfg.moe.bias_gamma > 0
     plan = pod_step = None
     if n_pods > 1:
+        if bias_rule:
+            raise NotImplementedError(
+                "a router's bias rule needs each pod's loads summed over `pod` "
+                "outside the exchange; the pod step has no such path")
         plan = shard_local_specs(p_abs, p_spec, mesh.shape, tcfg.sync)
-        pod_step = _make_pod_step(mesh, tcfg, p_spec, plan, loss_and_grads)
+
+        def pod_loss_and_grads(params, batch):
+            (loss, _), grads = loss_and_grads(params, batch)
+            return loss, grads
+
+        pod_step = _make_pod_step(mesh, tcfg, p_spec, plan, pod_loss_and_grads)
 
     def core(params, opt_state, residuals, batch):
         from ..dist import context as dist_context
 
         params = _constrain(params, p_shard)
         with dist_context.distribution(mesh):
+            counters = {}
             if pod_step is None:
-                loss, grads = loss_and_grads(params, batch)
+                (loss, aux), grads = loss_and_grads(params, batch)
+                counters = routing_counters(aux)
                 new_res = residuals
             else:
                 loss, grads, new_res = pod_step(params, batch, residuals)
+        if bias_rule:
+            grads = _without_bias(grads)
         new_params, new_opt, metrics = adamw_update(
             params, grads, opt_state, tcfg.optim
         )
+        if bias_rule:
+            new_params = with_bias_step(cfg, new_params, aux)
         new_params = _constrain(new_params, p_shard)
-        metrics = dict(metrics, loss=loss)
+        metrics = dict(metrics, loss=loss, **counters)
         return new_params, new_opt, new_res, metrics
 
     def make_jit(batch_tree):

@@ -234,6 +234,8 @@ class Trainer:
                         dt = time.perf_counter() - t0
                         loss = float(metrics["loss"])
                         grad_norm = float(metrics["grad_norm"])
+                        counters = {k: float(metrics[k]) for k in _COUNTERS
+                                    if k in metrics}
                 except _RECOVERABLE:  # device failure: roll back + replay
                     resumed = self.maybe_resume()
                     if not resumed:
@@ -251,6 +253,7 @@ class Trainer:
                     "dt": dt,
                     "data_s": data_s,
                     "compiled": fresh,
+                    **counters,
                 }
                 self.history.append(rec)
                 with span("trainer.control", step=n):
@@ -276,6 +279,7 @@ class Trainer:
                 print(
                     f"step {rec['step']:5d}  loss {rec['loss']:.4f}  "
                     f"gnorm {rec['grad_norm']:.3f}  {dt*1e3:.0f} ms"
+                    + "".join(f"  {k} {rec[k]:.4f}" for k in _COUNTERS if k in rec)
                 )
         if self._pending_save is not None:
             self._pending_save.join()
@@ -293,6 +297,11 @@ def _init_state(model_cfg: ModelConfig, tcfg: TrainConfig, n_pods: int, key):
         abstract_residuals(model_cfg, tcfg, n_pods),
     )
     return params, adamw_init(params, tcfg.optim), residuals
+
+
+# the train step's per-step counters of a model whose MoE layers record
+# their routing (``train_step.routing_counters``), copied into the history
+_COUNTERS = ("moe_load_peak", "moe_drop_share")
 
 
 class FaultInjected(RuntimeError):

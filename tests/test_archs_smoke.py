@@ -116,16 +116,17 @@ def test_decode_matches_full_forward(arch):
 
 
 def test_applicable_shape_skips():
-    """DESIGN.md §4: exactly 31 runnable cells with documented skips."""
+    """DESIGN.md §4: exactly 34 runnable cells with documented skips."""
     from repro.configs.registry import cells
 
     cs = cells()
-    assert len(cs) == 31
+    assert len(cs) == 34
     names = {(a, s.name) for a, s in cs}
     assert ("hubert-xlarge", "decode_32k") not in names
     assert ("hubert-xlarge", "long_500k") not in names
     assert ("minitron-8b", "long_500k") not in names
     assert ("deepseek-v3-671b", "long_500k") not in names
+    assert ("moonlight-16b-a3b", "long_500k") not in names
     assert ("rwkv6-7b", "long_500k") in names
     assert ("recurrentgemma-9b", "long_500k") in names
 
@@ -143,6 +144,7 @@ def test_full_configs_match_assignment():
         "hubert-xlarge": (48, 1280, 16, 16, 5120, 504),
         "recurrentgemma-9b": (38, 4096, 16, 1, 12288, 256000),
         "llama-3.2-vision-90b": (100, 8192, 64, 8, 28672, 128256),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }
     for arch, (L, d, h, kv, ff, v) in expect.items():
         cfg = get_config(arch)
@@ -153,6 +155,11 @@ def test_full_configs_match_assignment():
     dsv3 = get_config("deepseek-v3-671b")
     assert dsv3.moe.n_experts == 256 and dsv3.moe.top_k == 8
     assert dsv3.moe.n_shared == 1 and dsv3.moe.d_expert == 2048
+    assert (dsv3.moe.scoring, dsv3.moe.routed_scale) == ("sigmoid", 2.5)
+    moon = get_config("moonlight-16b-a3b")
+    assert (moon.moe.n_experts, moon.moe.top_k, moon.moe.n_shared) == (64, 6, 2)
+    assert (moon.moe.d_expert, moon.mla.q_lora_rank, moon.mla.kv_lora_rank) == (1408, None, 512)
+    assert (moon.moe.scoring, moon.moe.routed_scale) == ("sigmoid", 2.446)
     gran = get_config("granite-moe-3b-a800m")
     assert gran.moe.n_experts == 40 and gran.moe.top_k == 8
     assert get_config("qwen2.5-32b").qkv_bias
@@ -164,3 +171,4 @@ def test_param_counts_plausible():
     assert abs(active_param_count(get_config("deepseek-v3-671b")) / 1e9 - 37) < 3
     assert abs(param_count(get_config("deepseek-7b")) / 1e9 - 7) < 1
     assert abs(param_count(get_config("deepseek-coder-33b")) / 1e9 - 33) < 2
+    assert abs(param_count(get_config("moonlight-16b-a3b")) / 1e9 - 16) < 0.1

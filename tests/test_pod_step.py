@@ -92,7 +92,7 @@ def reference(cfg, params, batches):
     step, Adam's first moment, the parameters and each pod's residuals."""
     tdef = jax.tree.structure(params)
     grad = jax.jit(jax.value_and_grad(
-        lambda p, b: ts.loss_fn(cfg, p, b, jnp.float32)))
+        lambda p, b: ts.loss_fn(cfg, p, b, jnp.float32)[0]))
     opt = adamw_init(params, OPTIM)
     res = [[np.zeros(x.shape) for x in jax.tree.leaves(params)] for _ in range(PODS)]
     rows = BATCH // PODS
@@ -112,9 +112,20 @@ def reference(cfg, params, batches):
     return out
 
 
-def close(a, b, rtol):
+def close(a, b, rtol, atol=0.0):
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        np.testing.assert_allclose(x, y, rtol=rtol, atol=rtol * np.abs(y).max())
+        np.testing.assert_allclose(x, y, rtol=rtol,
+                                   atol=max(atol, rtol * np.abs(y).max()))
+
+
+# Adam moves an element by lr * m_hat / (sqrt(v_hat) + eps), and for an
+# element whose gradient is near eps that ratio swings by O(1) with a
+# round-off in g (the program and the reference sum the gradient in
+# different orders): one element of 16,384 differs by 1.06e-6 after a step
+# at lr 1e-3.  The parameters are compared to a hundredth of lr, 200 times
+# under what one element stepped the wrong way (2 lr) would show; the
+# moments, which carry g itself, keep their relative tolerance.
+PARAM_ATOL = 1e-2 * OPTIM.lr
 
 
 @pytest.fixture(scope="module")
@@ -139,7 +150,7 @@ def test_per_pod_step_matches_the_per_pod_reference(three_steps):
                                rtol=1e-5)
     for n, st in enumerate(states):
         close(st["m"], ref["m"][n], 1e-4)          # the synced gradients
-        close(st["params"], ref["params"][n], 1e-5)
+        close(st["params"], ref["params"][n], 1e-5, PARAM_ATOL)
         for p in range(PODS):
             close(jax.tree.map(lambda r: r[p], st["residuals"]),
                   ref["residuals"][n][p], 1e-4)
